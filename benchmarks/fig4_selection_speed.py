@@ -117,7 +117,7 @@ def _dispatch_rows():
 
     from repro.core import get_compressor
     from repro.core.compression import CompressionConfig
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
     from repro.dist.layout import build_layout
     from repro.launch.hlo_cost import count_wire_collectives
 
@@ -128,7 +128,7 @@ def _dispatch_rows():
     grads = jax.tree.map(jnp.zeros_like, params)
     resid = aggregate.init_residuals(params, msize)
     flat = jnp.zeros((layout.flat_size,))
-    mesh = AbstractMesh((("data", W), ("model", msize)))
+    mesh = AbstractMesh((W, msize), ("data", "model"))
 
     rows, bench = [], []
     for strategy in ("allgather", "gtopk"):
@@ -147,9 +147,9 @@ def _dispatch_rows():
 
         for method, fn, e_in in (("dispatch-perleaf", per_leaf, resid),
                                  ("dispatch-bucketed", bucketed, flat)):
-            sm = compat.shard_map(fn, mesh=mesh, in_specs=(P(), P()),
-                                  out_specs=P(), axis_names={"data"},
-                                  check_vma=False)
+            sm = jax.shard_map(fn, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=P(), axis_names={"data"},
+                               check_vma=False)
             msgs = count_wire_collectives(
                 jax.make_jaxpr(sm)(grads, e_in))["messages"]
             shape = f"L{L}-W{W}-{strategy}"

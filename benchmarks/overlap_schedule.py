@@ -50,7 +50,7 @@ def _dispatch_rows():
 
     from repro.core import get_compressor
     from repro.core.compression import CompressionConfig
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
     from repro.dist.layout import build_chunk_plan, build_layout
     from repro.launch.hlo_cost import count_wire_collectives
 
@@ -60,9 +60,8 @@ def _dispatch_rows():
     layout = build_layout(params, msize, ratio, spec)
     grads = jax.tree.map(jnp.zeros_like, params)
     flat = jnp.zeros((layout.flat_size,))
-    flat_mesh = AbstractMesh((("data", W), ("model", msize)))
-    pod_mesh = AbstractMesh((("pod", 2), ("data", W // 2),
-                             ("model", msize)))
+    flat_mesh = AbstractMesh((W, msize), ("data", "model"))
+    pod_mesh = AbstractMesh((2, W // 2, msize), ("pod", "data", "model"))
     cases = (
         ("allgather", flat_mesh, ("data",), False),
         ("hierarchical", pod_mesh, ("pod", "data"), True),
@@ -83,7 +82,7 @@ def _dispatch_rows():
                     resid2=r2s[0] if r2s else None).agg
 
             n_in = 3 if with_r2 else 2
-            sm = compat.shard_map(
+            sm = jax.shard_map(
                 agg_fn, mesh=mesh, in_specs=(P(),) * n_in, out_specs=P(),
                 axis_names=set(data_axes), check_vma=False)
             args = (grads, flat) + ((flat,) if with_r2 else ())

@@ -1,5 +1,5 @@
 """Distributed layer: sharding rules, compressed gradient aggregation and
-the shard_map version-compat shims.
+the compressed-gradient wire layout.
 
 ``sharding``   per-leaf PartitionSpec rules for the ``model`` axis plus the
                serve-time data-axis layouts (params, caches) and the
@@ -14,10 +14,8 @@ the shard_map version-compat shims.
                (``STRATEGIES``; DESIGN.md §3-§4, §7) — dispatched either
                per leaf (``aggregate_compressed``) or as ONE collective
                per wire level per step (``aggregate_bucketed``).
-``compat``     jax.shard_map partial-auto API across jax versions (plus
-               the ppermute shim the gTop-k rounds ride on).
 """
-from repro.dist import aggregate, compat, layout, sharding
+from repro.dist import aggregate, layout, sharding
 from repro.dist.aggregate import (STRATEGIES, AggregateResult,
                                   aggregate_bucketed,
                                   aggregate_bucketed_chunked,
@@ -35,7 +33,7 @@ from repro.dist.sharding import (cache_specs, param_spec, param_specs,
                                  train_state_specs)
 
 __all__ = [
-    "aggregate", "compat", "layout", "sharding",
+    "aggregate", "layout", "sharding",
     "STRATEGIES", "AggregateResult", "aggregate_bucketed",
     "aggregate_bucketed_chunked",
     "aggregate_compressed", "aggregate_dense", "bucket_compress",

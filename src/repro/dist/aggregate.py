@@ -89,7 +89,6 @@ from repro.core import adaptk, codec
 from repro.core.compression import CompressionConfig
 from repro.core.compressors import CompressorSpec
 from repro.core.error_feedback import resolve_backend
-from repro.dist import compat
 # geometry + wire model live in dist/layout.py (single source for both
 # dispatch granularities); re-exported here for API compatibility
 from repro.dist.layout import (STRATEGIES, BucketLayout,  # noqa: F401
@@ -479,7 +478,7 @@ def _gtopk_reduce_rounds(values, indices, axes, d_row: int, encode,
     granularities — ONE implementation of the subtlest invariant in the
     wire (the drop/group crediting of DESIGN.md §7), parametrized only
     by the re-encode step ``encode(dense) -> (values, indices)``."""
-    sizes = [compat.axis_size(a) for a in axes]
+    sizes = [jax.lax.axis_size(a) for a in axes]
     plan = gtopk_round_plan(sizes)
     dense = _decode_rows(values, indices, d_row, dtype)
     drop = jnp.zeros_like(dense)
@@ -495,8 +494,8 @@ def _gtopk_reduce_rounds(values, indices, axes, d_row: int, encode,
             sent = _decode_rows(v, i, d_row, dtype)
             drop = drop + (dense - sent) / group
         perm = [(j, j ^ mask) for j in range(sizes[pos])]
-        rv = compat.ppermute(v, axes[pos], perm)
-        ri = compat.ppermute(i, axes[pos], perm)
+        rv = jax.lax.ppermute(v, axes[pos], perm)
+        ri = jax.lax.ppermute(i, axes[pos], perm)
         dense = sent + _decode_rows(rv, ri, d_row, dtype)
     return dense, drop
 
@@ -631,7 +630,7 @@ def _wire_config(strategy: str, axes, resid2, world: int,
         # ``world`` (whose default of 1 would silently skip the rounds)
         world = 1
         for a in axes:
-            world *= compat.axis_size(a)
+            world *= jax.lax.axis_size(a)
         _log2_exact(world)
     if mc > 0.0 and hier:
         raise ValueError("momentum_correction reuses resid2 as the DGC "
@@ -645,7 +644,7 @@ def _wire_config(strategy: str, axes, resid2, world: int,
                          "config")
     if hier:
         outer_axis, inner_axes = axes[0], axes[1:]
-        n_pods = compat.axis_size(outer_axis)
+        n_pods = jax.lax.axis_size(outer_axis)
         n_inner = max(1, world // n_pods)
         if outer_gtopk:
             # the hybrid's outer merge is the recursive-doubling tree,

@@ -281,7 +281,6 @@ def measure_wire_time(mesh, layout: BucketLayout, spec, strategy: str, *,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat
     from repro.dist.aggregate import (_gather_mean, _gtopk_reduce_bucket,
                                       bucket_compress, encode_bucket_topk)
     from repro.launch.mesh import data_axes_of
@@ -318,9 +317,9 @@ def measure_wire_time(mesh, layout: BucketLayout, spec, strategy: str, *,
 
     if strategy in ("hierarchical", "hier_gtopk") and len(axes) < 2:
         raise ValueError(f"{strategy} needs >= 2 data axes on this mesh")
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         wire, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
-        axis_names=set(mesh.axis_names)))
+        axis_names=set(mesh.axis_names), check_vma=False))
     return _best_of(lambda: fn(values, indices, R2).block_until_ready(),
                     reps)
 
@@ -339,7 +338,6 @@ def measure_wire_pattern(mesh, pair_bytes: float, strategy: str, *,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat
     from repro.dist.aggregate import gtopk_round_plan
     from repro.launch.mesh import data_axes_of
     from repro.launch.topo import _best_of
@@ -356,8 +354,8 @@ def measure_wire_pattern(mesh, pair_bytes: float, strategy: str, *,
                 continue
             for _, mask, _ in gtopk_round_plan([sizes[ax]]):
                 perm = [(j, j ^ mask) for j in range(sizes[ax])]
-                v = compat.ppermute(v, ax, perm)
-                i = compat.ppermute(i, ax, perm)
+                v = jax.lax.ppermute(v, ax, perm)
+                i = jax.lax.ppermute(i, ax, perm)
                 v, i = jax.lax.optimization_barrier((v, i))
         return v, i
 
@@ -400,9 +398,9 @@ def measure_wire_pattern(mesh, pair_bytes: float, strategy: str, *,
         return consume(v * 1.0, i)
 
     def timed(f):
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-            axis_names=set(mesh.axis_names)))
+            axis_names=set(mesh.axis_names), check_vma=False))
         return _best_of(lambda: fn(v0, i0).block_until_ready(), reps)
 
     return max(timed(body) - timed(null), 1e-9)

@@ -11,55 +11,59 @@ in one fused pass lets the sequential refinement be replayed exactly on
 the resulting count table without touching HBM again — identical
 decisions, identical final threshold, 1 pass instead of ≤4.
 
-Like pass A the kernel streams ``g`` (+ optional ``e``) and forms ``u``
-in registers.  The ``triton`` lowering writes per-block count rows
-instead of revisiting one accumulator (GPU grid programs are parallel
-CTAs) and sums them outside the kernel — i32 addition is associative,
-so the combined counts are identical to the sequential grid's.
+Like pass A the kernel streams ``g`` (+ optional ``e``) as
+``(block // 128, 128)`` tiles and forms ``u`` in registers.  The
+sequential (mosaic) shape reads the thresholds from SMEM and keeps one
+``(8, 128)`` i32 count tile per threshold in a revisited accumulator;
+a grid step takes up to ``GROUP`` blocks.
+The ``triton`` lowering writes per-block count rows instead (GPU grid
+programs are parallel CTAs) and sums them outside the kernel — i32
+addition is associative, so the combined counts are identical to the
+sequential grid's.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ef_fused.tuning import gpu_compiler_params
-
-
-def _block_counts(refs, has_e: bool, n_t: int):
-    if has_e:
-        t_ref, g_ref, e_ref = refs[0], refs[1], refs[2]
-    else:
-        (t_ref, g_ref), e_ref = refs[:2], None
-    x = g_ref[0, :].astype(jnp.float32)
-    if has_e:
-        x = x + e_ref[0, :].astype(jnp.float32)
-    absx = jnp.abs(x)
-    t = t_ref[0, :n_t]                               # (n_t,) static slice
-    return jnp.sum((absx[None, :] > t[:, None]).astype(jnp.int32), axis=1)
+from repro.kernels.ef_fused.tuning import (GROUP, LANES, acc_rows,
+                                           block_rows, compiler_params,
+                                           fold_tiles)
 
 
-def _kernel(*refs, has_e: bool, n_t: int):
-    """Sequential-grid lowering: one revisited accumulator row."""
+def _load_abs_u(g_ref, e_ref):
+    x = g_ref[...].astype(jnp.float32)
+    if e_ref is not None:
+        x = x + e_ref[...].astype(jnp.float32)
+    return jnp.abs(x)
+
+
+def _kernel(t_ref, *refs, has_e: bool, n_t: int, sub: int):
+    """Sequential-grid lowering: one revisited count tile per threshold."""
     acc_ref = refs[-1]
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c = _block_counts(refs[:-1], has_e, n_t)
-    acc_ref[0, :n_t] = acc_ref[0, :n_t] + c
+    absx = _load_abs_u(refs[0], refs[1] if has_e else None)
+    for j in range(n_t):
+        c = fold_tiles((absx > t_ref[j]).astype(jnp.int32), sub, jnp.add)
+        acc_ref[j * sub:(j + 1) * sub] = acc_ref[j * sub:(j + 1) * sub] + c
 
 
-def _partials_kernel(*refs, has_e: bool, n_t: int):
+def _partials_kernel(t_ref, *refs, has_e: bool, n_t: int):
     """Parallel-grid (Triton) lowering: each program owns an output row."""
     acc_ref = refs[-1]
-    c = _block_counts(refs[:-1], has_e, n_t)
-    pad = jnp.zeros((128 - n_t,), jnp.int32)
-    acc_ref[0, :] = jnp.concatenate([c, pad])
+    absx = _load_abs_u(refs[0], refs[1] if has_e else None).reshape(-1)
+    t = t_ref[0, :n_t]                               # (n_t,) static slice
+    c = jnp.sum((absx[None, :] > t[:, None]).astype(jnp.int32), axis=1)
+    acc_ref[0, :] = jnp.concatenate([c, jnp.zeros((128 - n_t,), jnp.int32)])
 
 
 @functools.partial(jax.jit, static_argnames=("n_t", "block", "backend",
@@ -71,34 +75,40 @@ def tree_count(g2d: jax.Array, e2d: jax.Array | None, thresholds: jax.Array,
                interpret: bool = True):
     """Counts of ``|g + e| > thresholds[j]`` for ``j < n_t`` — one pass.
 
-    ``thresholds`` is a flat f32 vector of length ``n_t`` (padded to a
-    128-lane tile internally).  Returns an ``(n_t,)`` i32 count vector.
-    ``backend`` picks the kernel shape (see module docstring);
-    ``interpret`` picks the execution engine.
+    ``thresholds`` is a flat f32 vector of length ``n_t``.  Returns an
+    ``(n_t,)`` i32 count vector.  ``backend`` picks the kernel shape (see
+    module docstring); ``interpret`` picks the execution engine.
     """
     nblocks, b = g2d.shape
     assert b == block and 0 < n_t <= 128, (g2d.shape, block, n_t)
+    rows = block_rows(block)
+    sub = acc_rows(rows)
     has_e = e2d is not None
-    t = jnp.zeros((1, 128), jnp.float32).at[0, :n_t].set(
-        thresholds.astype(jnp.float32))
-    operands = (t, g2d, e2d) if has_e else (t, g2d)
-    data_spec = pl.BlockSpec((1, block), lambda i: (i, 0))
-    in_specs = [pl.BlockSpec((1, 128), lambda i: (0, 0))]
-    in_specs += [data_spec] * (len(operands) - 1)
-    parallel = backend == "triton"
-    acc_rows = nblocks if parallel else 1
-    row_spec = ((lambda i: (i, 0)) if parallel else (lambda i: (0, 0)))
-    kern = functools.partial(_partials_kernel if parallel else _kernel,
-                             has_e=has_e, n_t=n_t)
-    acc = pl.pallas_call(
-        kern,
-        grid=(nblocks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 128), row_spec),
-        out_shape=jax.ShapeDtypeStruct((acc_rows, 128), jnp.int32),
-        interpret=interpret,
-        compiler_params=gpu_compiler_params(backend, num_warps, num_stages),
-    )(*operands)
-    if parallel:
+    data = tuple(x.reshape(-1, LANES)
+                 for x in ((g2d, e2d) if has_e else (g2d,)))
+    group = 1 if backend == "triton" else math.gcd(nblocks, GROUP)
+    data_specs = [pl.BlockSpec((group * rows, LANES),
+                               lambda i: (i, 0))] * len(data)
+    params = compiler_params(backend, num_warps, num_stages)
+    t = thresholds.astype(jnp.float32).reshape(n_t)
+    if backend == "triton":
+        acc = pl.pallas_call(
+            functools.partial(_partials_kernel, has_e=has_e, n_t=n_t),
+            grid=(nblocks,),
+            in_specs=[pl.BlockSpec((1, 128), lambda i: (0, 0))] + data_specs,
+            out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((nblocks, 128), jnp.int32),
+            interpret=interpret,
+            compiler_params=params,
+        )(jnp.zeros((1, 128), jnp.float32).at[0, :n_t].set(t), *data)
         return jnp.sum(acc, axis=0)[:n_t]
-    return acc[0, :n_t]
+    acc = pl.pallas_call(
+        functools.partial(_kernel, has_e=has_e, n_t=n_t, sub=sub),
+        grid=(nblocks // group,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + data_specs,
+        out_specs=pl.BlockSpec((n_t * sub, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_t * sub, LANES), jnp.int32),
+        interpret=interpret,
+        compiler_params=params,
+    )(t, *data)
+    return jnp.sum(acc.reshape(n_t, -1), axis=1)

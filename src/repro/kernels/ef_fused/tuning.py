@@ -8,11 +8,13 @@ The fused EF pipeline (§8) lowers through three Pallas backends:
   partials + an order-preserving host-side fold, and a two-phase
   compact/residual split — no cross-program carried state, so the
   kernels are race-free on a real GPU);
-* ``interpret`` — the Pallas interpreter (CPU fallback / CI).
+* ``interpret`` — the Pallas interpreter running the mosaic kernels
+  (the CPU backend of the tests).
 
-``resolve_backend(None)`` picks the compiled lowering for the running
-platform — mosaic on TPU, triton on GPU — and the interpreter only as a
-last resort.  A ``use_backend(...)`` context or the
+``resolve_backend(None)`` picks the backend of the running platform —
+mosaic on TPU, triton on GPU, the interpreter on CPU — and raises on any
+other platform.  ``mosaic`` never runs interpreted: off a TPU it fails
+to compile rather than emulate.  A ``use_backend(...)`` context or the
 ``REPRO_KERNEL_BACKEND`` env var overrides the default process-wide
 (this is how the CI ``triton-interpret`` leg forces the GPU code path
 through the interpreter on a CPU runner), and an explicit ``backend=``
@@ -26,12 +28,11 @@ Block sizes are resolved per ``(backend, shape-class, dtype)`` as a
 2. else the checked-in table ``benchmarks/baselines/
    kernelconfig.<platform>.json`` is consulted (CI pins the chosen
    configs; steady-state steps pay zero autotune cost);
-3. else the in-process autotune cache;
-4. else, on a compiled backend, a measured autotune over a small
-   candidate grid (each candidate timed once with
-   ``block_until_ready``); under the interpreter the deterministic
-   bounded-block heuristic is used instead — interpreter timings would
-   only measure emulation overhead.
+3. else the in-process cache;
+4. else the deterministic heuristic.  A measured autotune over a small
+   candidate grid (each candidate timed with ``block_until_ready``)
+   runs only when asked for (``measure=True``: the table writer), never
+   from inside a traced step.
 
 Per-dtype block minima: TPU tiles are ``(sublanes, 128)`` lanes with
 sublanes = 32 / itemsize (f32 → 8×128 = 1024, bf16 → 16×128 = 2048),
@@ -62,11 +63,10 @@ MAX_INTERPRET_STATS_BLOCKS = 4
 INTERPRET_MIN_BLOCK = 2048
 
 _PLATFORM_BACKEND = {"tpu": "mosaic", "gpu": "triton", "cuda": "triton",
-                     "rocm": "triton"}
-# platforms on which each compiled backend actually compiles; anywhere
-# else the lowering runs under the Pallas interpreter (same kernel code,
-# emulated execution — the CI smoke path for the GPU lowering)
-_COMPILES_ON = {"mosaic": ("tpu",), "triton": ("gpu", "cuda", "rocm")}
+                     "rocm": "triton", "cpu": "interpret"}
+# platforms on which the triton lowering compiles; anywhere else it runs
+# under the Pallas interpreter (the CI smoke path for the GPU lowering)
+_COMPILES_ON = {"triton": ("gpu", "cuda", "rocm")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,8 +111,14 @@ def _platform() -> str:
 
 
 def default_backend(platform: Optional[str] = None) -> str:
-    """The compiled lowering for ``platform`` — interpreter last resort."""
-    return _PLATFORM_BACKEND.get(platform or _platform(), "interpret")
+    """The kernel backend for ``platform``: the compiled lowering on a
+    TPU or GPU, the interpreter on the CPU; any other platform raises."""
+    platform = platform or _platform()
+    try:
+        return _PLATFORM_BACKEND[platform]
+    except KeyError:
+        raise ValueError(f"no kernel backend for platform {platform!r}; "
+                         f"have {sorted(_PLATFORM_BACKEND)}") from None
 
 
 @contextmanager
@@ -173,31 +179,67 @@ def resolve_backend(backend: Optional[str] = None,
     return default_backend(platform)
 
 
-def gpu_compiler_params(backend: str, num_warps: int = 4,
-                        num_stages: int = 2):
-    """``TritonCompilerParams`` for the triton lowering, ``None`` elsewhere.
+def compiler_params(backend: str, num_warps: int = 4, num_stages: int = 2):
+    """Pallas compiler params for the kernel shape of ``backend``.
 
-    Harmless under the interpreter (Pallas ignores compiler params it
-    does not lower through), so the triton kernel shape carries its warp
-    configuration unconditionally.
+    The triton shape carries its warp configuration; the sequential
+    shape (mosaic, and the interpreter running the same kernels) states
+    its grid as ``arbitrary`` — a revisited accumulator or a carried
+    scratch scalar orders every grid step after the previous one.  The
+    interpreter ignores both.
     """
-    if backend != "triton":
-        return None
-    from jax.experimental.pallas import triton as plgpu
-    return plgpu.TritonCompilerParams(num_warps=num_warps,
-                                      num_stages=num_stages)
+    if backend == "triton":
+        from jax.experimental.pallas import triton as plgpu
+        return plgpu.CompilerParams(num_warps=num_warps,
+                                    num_stages=num_stages)
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def exec_interpret(backend: str, platform: Optional[str] = None) -> bool:
-    """Whether ``backend`` must run under the Pallas interpreter here.
+    """Whether ``backend`` runs under the Pallas interpreter here.
 
-    A compiled backend requested off its platform (the ``triton``
-    smoke leg on a CPU runner, mosaic emulation in tests) keeps its
-    kernel structure and block policy but executes interpreted.
+    ``interpret`` always does and ``mosaic`` never does: it compiles for
+    the TPU or raises.  ``triton`` off a GPU keeps its kernel structure
+    but executes interpreted (the CPU smoke leg of the GPU lowering).
     """
     if backend == "interpret":
         return True
+    if backend == "mosaic":
+        return False
     return (platform or _platform()) not in _COMPILES_ON[backend]
+
+
+# ---------------------------------------------------------------------------
+# TPU tile geometry shared by the sequential kernel shape
+# ---------------------------------------------------------------------------
+
+LANES = 128            # minor dim of every kernel operand view
+SUBLANES = 8           # f32 sublanes: height of one (8, 128) vreg tile
+GROUP = 8              # blocks per sequential grid step (at most)
+
+
+def block_rows(block: int) -> int:
+    """Rows of the ``(rows, LANES)`` view one ``block``-element block spans."""
+    if block % LANES:
+        raise ValueError(f"block {block} is not a multiple of {LANES} lanes")
+    return block // LANES
+
+
+def acc_rows(rows: int) -> int:
+    """Height of the accumulator tile a ``rows``-row block folds into:
+    one (8, 128) tile, or the whole block when it is shorter."""
+    return SUBLANES if rows % SUBLANES == 0 else rows
+
+
+def fold_tiles(x, sub: int, op):
+    """Fold a ``(rows, LANES)`` value into one ``(sub, LANES)`` tile,
+    sub-tile by sub-tile in row order.  Elementwise ops only, so every
+    lowering combines the same elements in the same order."""
+    acc = x[0:sub]
+    for r in range(sub, x.shape[0], sub):
+        acc = op(acc, x[r:r + sub])
+    return acc
 
 
 # ---------------------------------------------------------------------------
@@ -384,15 +426,16 @@ def resolve_config(d: int, dtype="float32", *,
                    backend: Optional[str] = None,
                    interpret: Optional[bool] = None,
                    platform: Optional[str] = None,
-                   measure: Optional[bool] = None,
+                   measure: bool = False,
                    timer=None) -> KernelConfig:
     """The resolution ladder of the module docstring, cached per
     ``(backend, shape-class, dtype)``.
 
-    ``measure`` overrides the measured-autotune decision: ``None``
-    measures only when the backend actually compiles here (interpreter
-    timings are emulation noise), ``True``/``False`` force it either
-    way (tests inject a stub ``timer``).
+    Without ``measure`` the result is deterministic: the table, else the
+    heuristic.  ``measure=True`` times the candidate grid instead (the
+    table writer; tests inject a stub ``timer``) — never from inside a
+    traced step, where timed candidates would pick the block geometry
+    and with it the summation order.
     """
     backend = resolve_backend(backend, interpret, platform)
     key = config_key(backend, d, dtype)
@@ -405,8 +448,6 @@ def resolve_config(d: int, dtype="float32", *,
                                       backend=backend, source="table")
             _CACHE[key] = cfg
             return cfg
-    if measure is None:
-        measure = not exec_interpret(backend, platform)
     if measure:
         cfg = autotune_measure(backend, d, dtype, timer=timer)
     else:
@@ -435,6 +476,9 @@ def write_table(path: Optional[str] = None, *, ds=TABLE_DS,
 
     platform = jax.default_backend()
     backend = resolve_backend(backend, None, platform)
+    if measure is None:
+        # interpreter timings would only measure emulation overhead
+        measure = not exec_interpret(backend, platform)
     configs = {}
     for dtype in dtypes:
         for d in ds:

@@ -1,7 +1,9 @@
-"""Pallas TPU kernel: count elements with |x| > threshold (one HBM pass).
+"""Count elements with |x| > threshold (one HBM pass).
 
 This is the inner reduction of Algorithm 1's refinement loop (lines 6-7):
 each refinement iteration re-counts the mask at the adjusted threshold.
+The kernel is the fused pipeline's ``ef_fused.tree_count`` at a single
+threshold.
 """
 from __future__ import annotations
 
@@ -9,37 +11,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-
-def _count_kernel(t_ref, x_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)
-    c = jnp.sum((jnp.abs(x) > t_ref[0, 0]).astype(jnp.int32))
-    acc_ref[0, 0] = acc_ref[0, 0] + c
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def count_gt(x2d: jax.Array, thres: jax.Array, *, block: int = 2048,
              interpret: bool = True) -> jax.Array:
     """# of elements of (nblocks, block) ``x2d`` with |x| > thres (scalar)."""
-    nblocks, b = x2d.shape
-    assert b == block
-    t = jnp.asarray(thres, jnp.float32).reshape(1, 1)
-    acc = pl.pallas_call(
-        _count_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        interpret=interpret,
-    )(t, x2d)
-    return acc[0, 0]
+    from repro.kernels.ef_fused.tree_count import tree_count
+
+    t = jnp.asarray(thres, jnp.float32).reshape(1)
+    return tree_count(x2d, None, t, n_t=1, block=block, interpret=interpret,
+                      backend="interpret" if interpret else "mosaic")[0]

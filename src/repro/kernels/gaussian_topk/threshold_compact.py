@@ -1,73 +1,33 @@
-"""Pallas TPU kernel: threshold-mask compaction via one-hot MXU matmul.
+"""Threshold-mask block compaction into fixed-width staging rows.
 
 The TPU-native replacement for the GPU's variable-length masked write
-(paper §3.3): each (1, B) VMEM tile of the flat gradient selects its
-|x| > thres elements and compacts them into a fixed per-block staging
-buffer of width ``bcap`` using a one-hot (bcap × B) matrix product —
-the compaction IS a matmul, so it runs on the MXU instead of serialised
-scalar scatters.  Local offsets stay < B ≤ 2^24 so f32 index arithmetic
-is exact; global indices are reconstructed in ops.py as i*B + offset.
+(paper §3.3): each block of the flat gradient selects its |x| > thres
+elements and compacts them, in index order, into a fixed per-block
+staging buffer of width ``bcap``.  Global indices are reconstructed in
+ops.py as i*B + offset.  The kernel is the fused pipeline's
+``ef_fused.compact_residual`` without the residual write.
 
 Outputs (per block row i):
   vals   (nblocks, bcap) f32   selected values, in index order
   offs   (nblocks, bcap) i32   local offsets (SENTINEL = -1 padding)
-  counts (nblocks, 128)  i32   [i, 0] = #selected in block i (uncapped)
+  counts (nblocks,)      i32   #selected in block i (uncapped)
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-SENTINEL = -1
-
-
-def _compact_kernel(t_ref, x_ref, vals_ref, offs_ref, cnt_ref, *, bcap: int):
-    x = x_ref[0, :].astype(jnp.float32)          # (B,)
-    b = x.shape[0]
-    thres = t_ref[0, 0]
-    mask = jnp.abs(x) > thres                     # (B,)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1  # (B,) compacted position
-    keep = mask & (pos < bcap)
-    # one-hot compaction matrix (bcap, B) — MXU matmul does the gather
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bcap, b), 0)
-    oh = ((rows == pos[None, :]) & keep[None, :]).astype(jnp.float32)
-    vals = oh @ x                                  # (bcap,)
-    offs_f = oh @ jax.lax.broadcasted_iota(jnp.float32, (b,), 0)
-    got = jnp.arange(bcap, dtype=jnp.int32) < jnp.minimum(cnt, bcap)
-    offs = jnp.where(got, offs_f.astype(jnp.int32), SENTINEL)
-    vals_ref[0, :] = vals
-    offs_ref[0, :] = offs
-    cnt_ref[0, 0] = cnt
+from repro.core.codec import SENTINEL  # noqa: F401  (re-exported)
 
 
 @functools.partial(jax.jit, static_argnames=("bcap", "block", "interpret"))
 def threshold_compact(x2d: jax.Array, thres: jax.Array, *, bcap: int,
                       block: int = 2048, interpret: bool = True):
-    nblocks, b = x2d.shape
-    assert b == block and bcap % 8 == 0, (x2d.shape, block, bcap)
-    t = jnp.asarray(thres, jnp.float32).reshape(1, 1)
-    kern = functools.partial(_compact_kernel, bcap=bcap)
-    vals, offs, cnts = pl.pallas_call(
-        kern,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bcap), lambda i: (i, 0)),
-            pl.BlockSpec((1, bcap), lambda i: (i, 0)),
-            pl.BlockSpec((1, 128), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, bcap), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, bcap), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, 128), jnp.int32),
-        ],
-        interpret=interpret,
-    )(t, x2d)
-    return vals, offs, cnts[:, 0]
+    from repro.kernels.ef_fused.compact_residual import compact_residual
+
+    vals, offs, cnts, _ = compact_residual(
+        x2d, None, thres, bcap=bcap, k_cap=x2d.shape[0] * bcap, block=block,
+        with_resid=False, interpret=interpret,
+        backend="interpret" if interpret else "mosaic")
+    return vals, offs, cnts

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: one-pass log2-magnitude histogram of |x|.
+"""One-pass log2-magnitude histogram of |x|.
 
 Beyond-paper optimization (DESIGN.md §Perf): Algorithm 1 needs up to four
 extra count passes over u to refine the ppf threshold.  A 64-bin histogram
@@ -9,9 +9,9 @@ is then read off the cumulative histogram on the host side of the jit
 keeps the selected count within ~19% of k — comparable to Algorithm 1's
 [2k/3, 4k/3] accept band, at 1 pass instead of up to 5.
 
-The per-tile histogram is computed as a one-hot (bins × B) matmul — the
-same MXU trick as threshold_compact — and accumulated across the
-sequential grid into a revisited (1, bins) output tile.
+The histogram pass is the fused pipeline's pass A with its histogram
+on (``ef_fused.fused_moments``): per-bin counts accumulate across the
+sequential grid in revisited (8, 128) i32 tiles.
 """
 from __future__ import annotations
 
@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 BINS = 128        # 1/4-exponent bins covering 2^-16 .. 2^16
 _LO_EXP = -16.0
@@ -38,33 +37,12 @@ def bin_lower_edge(b):
     return 2.0 ** (b / _SCALE + _LO_EXP)
 
 
-def _hist_kernel(x_ref, h_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        h_ref[...] = jnp.zeros_like(h_ref)
-
-    x = jnp.abs(x_ref[0, :].astype(jnp.float32))      # (B,)
-    b = _bin_of(x)                                    # (B,)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (BINS, x.shape[0]), 0)
-    oh = (rows == b[None, :]).astype(jnp.float32)     # (BINS, B)
-    h = oh @ jnp.ones((x.shape[0],), jnp.float32)     # (BINS,)
-    h_ref[0, :] = h_ref[0, :] + h
-
-
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def abs_histogram(x2d: jax.Array, *, block: int = 2048,
                   interpret: bool = True) -> jax.Array:
     """(BINS,) histogram of |x| magnitude buckets over (nblocks, block)."""
-    nblocks, b = x2d.shape
-    assert b == block
-    h = pl.pallas_call(
-        _hist_kernel,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BINS), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, BINS), jnp.float32),
-        interpret=interpret,
-    )(x2d)
-    return h[0]
+    from repro.kernels.ef_fused.fused_moments import fused_moments
+
+    return fused_moments(x2d, None, block=block, with_hist=True,
+                         interpret=interpret,
+                         backend="interpret" if interpret else "mosaic")[3]

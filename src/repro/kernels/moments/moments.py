@@ -1,56 +1,24 @@
-"""Pallas TPU kernel: single-pass moments (sum, sum-of-squares, abs-max).
+"""Single-pass moments (sum, sum-of-squares, abs-max) of a blocked vector.
 
 Algorithm 1 line 2 of the paper computes mean/std of the d-dimensional
-accumulated gradient every iteration.  On GPU this is two cheap library
-reductions; on TPU we fuse all three statistics into ONE pass over HBM
-(u is read once into VMEM tiles, three scalars accumulate across the
-sequential grid), which makes Gaussian_k's statistics phase strictly
-memory-bound at one |u| read.
-
-Layout: the flat vector is reshaped to (nblocks, block) by ops.py; the
-kernel runs a 1-D sequential grid over rows with a (1, block) VMEM tile
-and a (3,)-scalar SMEM-style accumulator implemented as a (1, 128) f32
-output revisited by every grid step (TPU grids are sequential).
+accumulated gradient every iteration.  On TPU all three statistics fuse
+into ONE pass over HBM.  The kernel is pass A of the fused EF pipeline
+(``ef_fused.fused_moments``) run on a single operand, so the unfused
+pipeline's statistics are bit-for-bit the fused pipeline's.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-
-def _moments_kernel(x_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)
-    s = jnp.sum(x)
-    sq = jnp.sum(x * x)
-    mx = jnp.max(jnp.abs(x))
-    acc = acc_ref[0, :]
-    new = jnp.concatenate([
-        (acc[0] + s)[None], (acc[1] + sq)[None],
-        jnp.maximum(acc[2], mx)[None], acc[3:],
-    ])
-    acc_ref[0, :] = new
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def moments(x2d: jax.Array, *, block: int = 2048, interpret: bool = True):
     """Return (sum, sumsq, absmax) of a (nblocks, block) f32/bf16 array."""
-    nblocks, b = x2d.shape
-    assert b == block, (x2d.shape, block)
-    acc = pl.pallas_call(
-        _moments_kernel,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 128), jnp.float32),
-        interpret=interpret,
-    )(x2d)
-    return acc[0, 0], acc[0, 1], acc[0, 2]
+    from repro.kernels.ef_fused.fused_moments import fused_moments
+
+    s, sq, mx, _ = fused_moments(
+        x2d, None, block=block, interpret=interpret,
+        backend="interpret" if interpret else "mosaic")
+    return s, sq, mx

@@ -117,6 +117,30 @@ def describe_env(base: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     return {var: base[var] for var in RECORDED_VARS if var in base}
 
 
+CACHE_DIR_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at ``.jax_cache`` in
+    the checkout: a fixed path, since the path is part of every cache
+    key.  Called by the launch entry points before their first compile —
+    never at import and never from tests.
+    """
+    path = os.environ.get(CACHE_DIR_VAR)
+    if path:
+        return path
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    path = os.path.join(root, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def shell_lines(host_devices: Optional[int] = None) -> list:
     """``export`` lines for run.sh (evaluated before Python starts)."""
     return [f"export {var}={value!r}"

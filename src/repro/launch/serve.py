@@ -52,6 +52,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices}")
 
+    from repro.launch.env import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

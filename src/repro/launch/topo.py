@@ -216,7 +216,6 @@ def _axis_ring_time(mesh, axis: str, nbytes: int, rounds: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat
 
     n = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     if n < 2:
@@ -227,7 +226,7 @@ def _axis_ring_time(mesh, axis: str, nbytes: int, rounds: int,
     def body_rounds(r):
         def body(x):
             for _ in range(r):
-                x = compat.ppermute(x, axis, perm)
+                x = jax.lax.ppermute(x, axis, perm)
                 (x,) = jax.lax.optimization_barrier((x,))
             return x * 1.0
         return body
@@ -235,9 +234,9 @@ def _axis_ring_time(mesh, axis: str, nbytes: int, rounds: int,
     x = jnp.ones((words,), jnp.float32)
     times = []
     for r in (0, rounds):
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body_rounds(r), mesh=mesh, in_specs=P(), out_specs=P(),
-            axis_names=set(mesh.axis_names)))
+            axis_names=set(mesh.axis_names), check_vma=False))
         times.append(_best_of(lambda: fn(x).block_until_ready(), reps))
     return max(0.0, times[1] - times[0]) / rounds
 

@@ -128,11 +128,23 @@ def parse_args(argv=None):
 
 
 def main(argv=None):
+    train(argv)
+    return 0
+
+
+def train(argv=None) -> list:
+    """Run the training loop; returns one record per logged step: ``step``,
+    ``loss``, ``comm_frac`` (None for dense) and ``seconds``, the host
+    wall time since the previous record (the first includes compiling).
+    """
     args = parse_args(argv)
     if args.host_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices}")
 
+    from repro.launch.env import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
     from repro.checkpoint import load_state, save_state
@@ -277,6 +289,17 @@ def main(argv=None):
     step = make_train_step(cfg, mesh, opt, lr_fn, compression=config,
                            remat=not args.smoke, seed=args.seed,
                            layout=layout)
+    # place the state as the step returns it, so step 1 reuses step 0's
+    # executable instead of compiling again for new input shardings
+    from jax.sharding import NamedSharding
+
+    from repro.dist.sharding import train_state_specs
+    from repro.launch.mesh import data_axes_of
+
+    axes_d = data_axes_of(mesh)
+    state = jax.device_put(state, jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        train_state_specs(state, axes_d if len(axes_d) > 1 else axes_d[0])))
 
     print(f"arch={cfg.name} compressor={args.compressor} ratio={args.ratio} "
           f"strategy={strategy}{'(auto)' if decision is not None else ''} "
@@ -288,7 +311,8 @@ def main(argv=None):
         from repro.serve import RESYNC, message_bits, publish
         pub_key = jax.random.fold_in(jax.random.PRNGKey(args.seed), 0x9B)
         pub_bits, n_deltas, n_resyncs = 0, 0, 0
-    t0 = time.time()
+    history = []
+    t0 = t_last = time.time()
     for i in range(args.steps):
         batch = batch_for(cfg, i, global_batch=args.batch, seq_len=args.seq,
                           seed=args.seed)
@@ -303,7 +327,9 @@ def main(argv=None):
             else:
                 n_deltas += 1
         if i % args.log_every == 0 or i == args.steps - 1:
-            comm = ""
+            loss = float(m["loss"])
+            t_now = time.time()
+            comm, r = "", None
             if "comm_bits_sparse" in m:
                 r = float(m["comm_bits_sparse"]) / float(m["comm_bits_dense"])
                 comm = f" comm_frac={r:.4f}"
@@ -315,9 +341,12 @@ def main(argv=None):
                 # record the auto decision alongside the step metrics
                 comm += (f" tuner={decision.strategy}"
                          f" pred_wire_us={decision.best.total_s * 1e6:.1f}")
-            print(f"step {i:5d} loss={float(m['loss']):.4f} "
+            print(f"step {i:5d} loss={loss:.4f} "
                   f"lr={float(m['lr']):.4g}{comm} "
-                  f"({time.time() - t0:.1f}s)", flush=True)
+                  f"({t_now - t0:.1f}s)", flush=True)
+            history.append({"step": i, "loss": loss, "comm_frac": r,
+                            "seconds": t_now - t_last})
+            t_last = t_now
     if pub_state is not None:
         print(f"published {n_deltas} deltas + {n_resyncs} resyncs "
               f"({pub_bits / 8 / 2 ** 20:.3f} MiB on the wire)")
@@ -325,7 +354,7 @@ def main(argv=None):
         save_state(args.checkpoint, dict(state, publish=pub_state)
                    if pub_state is not None else state)
         print(f"saved -> {args.checkpoint}")
-    return 0
+    return history
 
 
 if __name__ == "__main__":

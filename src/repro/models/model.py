@@ -161,10 +161,7 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
             aux = aux + a
         if cfg.shard_activations:
             # §Perf knob: store the layer-boundary carry model-sharded
-            # (identity on jax 0.4.x, where the constraint is illegal
-            # inside the full-manual shard_map region — see dist/compat)
-            from repro.dist import compat
-            h = compat.auto_axis_constraint(
+            h = jax.lax.with_sharding_constraint(
                 h, PartitionSpec(None, None, "model"))
         return h, aux
 

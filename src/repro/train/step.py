@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.compression import CompressionConfig, as_config
-from repro.dist import aggregate, compat
+from repro.dist import aggregate
 from repro.dist.layout import build_chunk_plan
 from repro.dist.sharding import batch_specs, param_spec, train_state_specs
 from repro.launch.mesh import data_axes_of, data_world_size, model_axis_size
@@ -37,11 +37,7 @@ def constrain_params(params, model_axis: str, msize: int):
     """Pin the model-axis sharding of every param leaf inside the
     partial-manual region — input shardings on auto axes do not survive
     the shard_map boundary, and without this the whole model computes
-    replicated over ``model``.  (On jax 0.4.x the constraint op is
-    unsupported inside partial-auto regions and degrades to identity —
-    see dist/compat.py; numerics are unaffected.)"""
-    if not compat.supports_auto_axis_constraints():
-        return params  # skip computing the specs entirely on 0.4.x
+    replicated over ``model``."""
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: jax.lax.with_sharding_constraint(
             leaf, param_spec(path, leaf, model_axis, msize)),
@@ -55,7 +51,7 @@ def _joint(data_axes):
 def worker_index(data_axes):
     idx = jnp.int32(0)
     for a in data_axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -276,7 +272,7 @@ def make_train_step(cfg, mesh, optimizer: Optimizer, lr_fn: Callable,
 
     @jax.jit
     def step_fn(state, batch):
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             per_worker_step, mesh=mesh,
             in_specs=(train_state_specs(state, joint),
                       batch_specs(batch, joint)),

@@ -111,7 +111,7 @@ def check_gtopk():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
 
     mesh = make_mesh((4, 2), ("data", "model"))
     W = data_world_size(mesh)
@@ -133,10 +133,10 @@ def check_gtopk():
             "model", msize, jax.random.PRNGKey(7), world=W)
         return res.agg["w"], res.resid["w"][None], res.metrics
 
-    sm = compat.shard_map(body, mesh=mesh,
-                          in_specs=(P("data"), P("data")),
-                          out_specs=(P(), P("data"), P()),
-                          axis_names={"data"}, check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("data"), P("data")),
+                       out_specs=(P(), P("data"), P()),
+                       axis_names={"data"}, check_vma=False)
     agg_mesh, new_e_mesh, metrics = jax.jit(sm)(g, e)
 
     outs = [aggregate.compress_worker(g[w], e[w], spec, ratio, msize, None)
@@ -264,7 +264,7 @@ def check_adaptk():
     from jax.sharding import PartitionSpec as P
 
     from repro.core import adaptk
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
 
     spec = get_compressor("topk")
     policy = adaptk.make_policy("variance")
@@ -298,9 +298,9 @@ def check_adaptk():
         in_specs = (P(joint), P(joint)) + ((P(joint),) if with_r2 else ())
         out_specs = (P(), P(joint), P()) + ((P(joint),) if with_r2
                                             else ())
-        sm = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs,
-                              axis_names=set(data_axes), check_vma=False)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs,
+                           axis_names=set(data_axes), check_vma=False)
         args = (g, e) + ((r2,) if with_r2 else ())
         return jax.jit(sm)(*args)
 
@@ -394,7 +394,7 @@ def check_rtopk():
     from jax.sharding import PartitionSpec as P
 
     from repro.core import adaptk
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
 
     spec = get_compressor("rtopk")
     ratio, d, msize = 0.02, 407, 2
@@ -424,9 +424,9 @@ def check_rtopk():
 
         in_specs = (P(joint), P(joint)) + ((P(joint),) if with_r2 else ())
         out_specs = (P(), P(joint)) + ((P(joint),) if with_r2 else ())
-        sm = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs,
-                              axis_names=set(data_axes), check_vma=False)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs,
+                           axis_names=set(data_axes), check_vma=False)
         args = (g, e) + ((r2,) if with_r2 else ())
         return jax.jit(sm)(*args)
 
@@ -512,7 +512,7 @@ def check_rtopk():
         return (res.agg["w"], res.resid["w"][None], res.adapt_state,
                 res.metrics["k_total"])
 
-    run = jax.jit(compat.shard_map(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P("data"), P()),
         out_specs=(P(), P("data"), P(), P()),
         axis_names={"data"}, check_vma=False))
@@ -563,7 +563,7 @@ def check_bucketed():
     from jax.sharding import PartitionSpec as P
 
     from repro.core.adaptk import make_policy
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
     from repro.dist.layout import build_layout, pack_residual_arrays
     from repro.launch.hlo_cost import count_wire_collectives
 
@@ -624,12 +624,12 @@ def check_bucketed():
             out = (res.agg, res.resid[None], res.metrics)
             return out + ((res.resid2[None],) if r2s else ())
 
-        sm1 = compat.shard_map(
+        sm1 = jax.shard_map(
             per_leaf, mesh=mesh, in_specs=(P(joint),) * (2 + with_r2),
             out_specs=(P(), P(joint), P()) + ((P(joint),) if with_r2
                                               else ()),
             axis_names=set(data_axes), check_vma=False)
-        sm2 = compat.shard_map(
+        sm2 = jax.shard_map(
             bucketed, mesh=mesh, in_specs=(P(joint),) * (2 + with_r2),
             out_specs=(P(), P(joint), P()) + ((P(joint),) if with_r2
                                               else ()),
@@ -719,7 +719,7 @@ def check_chunked():
     from jax.sharding import PartitionSpec as P
 
     from repro.core.adaptk import make_policy
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
     from repro.dist.layout import build_chunk_plan, build_layout
     from repro.launch.hlo_cost import count_wire_collectives
 
@@ -776,12 +776,12 @@ def check_chunked():
             in_specs=(P(joint),) * (2 + with_r2),
             out_specs=(P(), P(joint), P()) + ((P(joint),) if with_r2
                                               else ()))
-        sm1 = compat.shard_map(unchunked, mesh=mesh,
-                               axis_names=set(data_axes),
-                               check_vma=False, **specs)
-        sm2 = compat.shard_map(chunked, mesh=mesh,
-                               axis_names=set(data_axes),
-                               check_vma=False, **specs)
+        sm1 = jax.shard_map(unchunked, mesh=mesh,
+                            axis_names=set(data_axes),
+                            check_vma=False, **specs)
+        sm2 = jax.shard_map(chunked, mesh=mesh,
+                            axis_names=set(data_axes),
+                            check_vma=False, **specs)
         args = (g_stack, e_flat) + ((r2_flat,) if with_r2 else ())
         out1 = jax.jit(sm1)(*args)
         out2 = jax.jit(sm2)(*args)
@@ -1000,7 +1000,7 @@ def check_hier_gtopk():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
 
     spec = get_compressor("topk")
     ratio, d = 0.02, 407
@@ -1023,10 +1023,10 @@ def check_hier_gtopk():
                     res.resid2["w"][None],
                     res.metrics["collectives_per_step"])
 
-        sm = compat.shard_map(body, mesh=mesh,
-                              in_specs=(P(joint), P(joint), P(joint)),
-                              out_specs=(P(), P(joint), P(joint), P()),
-                              axis_names=set(data_axes), check_vma=False)
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(joint), P(joint), P(joint)),
+                           out_specs=(P(), P(joint), P(joint), P()),
+                           axis_names=set(data_axes), check_vma=False)
         return jax.jit(sm)(g, e, r2)
 
     def simulate(W, n_pods, msize, g, e, r2):

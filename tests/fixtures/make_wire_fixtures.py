@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.compression import CompressionConfig
 from repro.core.compressors import get_compressor
-from repro.dist import compat
 from repro.dist.aggregate import aggregate_bucketed
 from repro.dist.layout import build_layout
 from repro.launch.mesh import make_mesh
@@ -71,9 +70,9 @@ def compile_wire(strategy, shape, axes_names):
     in_specs = (gspec, P(data_axes)) + ((P(data_axes),) if needs_r2 else ())
     out_specs = (jax.tree.map(lambda _: P(), params), P(data_axes)) + (
         (P(data_axes),) if needs_r2 else ())
-    fn = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs,
-                                  axis_names=set(axes_names)))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs,
+                               axis_names=set(axes_names), check_vma=False))
     D = layout.model_size * layout.d_row_total
     g = {k: jnp.zeros((world,) + s) for k, s in PARAMS.items()}
     e = jnp.zeros((world, D))

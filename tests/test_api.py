@@ -29,8 +29,9 @@ from repro.core import get_compressor
 from repro.core.adaptk import make_policy
 from repro.core.compression import (DENSE, STRATEGIES, CompressionConfig,
                                     as_config)
-from repro.dist import aggregate, compat
+from repro.dist import aggregate
 from repro.dist.aggregate import AggregateResult
+from repro.launch.mesh import make_mesh
 
 MSIZE, RATIO = 2, 0.1
 
@@ -134,13 +135,13 @@ def _grads():
 def _run_per_leaf(call):
     """Run an aggregate_compressed spelling on the (1,1) mesh (the
     per-leaf path needs a live data axis, like tests/test_layout.py)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     grads = _grads()
     resid = aggregate.init_residuals(grads, MSIZE)
     body = lambda g, e: call(g, e)  # noqa: E731
-    sm = compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=P(), axis_names={"data"},
-                          check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(), axis_names={"data"},
+                       check_vma=False)
     return jax.jit(sm)(grads, resid)
 
 
@@ -232,7 +233,7 @@ def test_make_train_step_legacy_kwargs_warn():
     from repro.optim import sgd_momentum
     from repro.train import make_train_step
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     loss = lambda p, b: (jnp.sum(p["w"] * b), {})  # noqa: E731
     with pytest.warns(DeprecationWarning, match="make_train_step"):
         make_train_step(None, mesh, sgd_momentum(0.9), lambda s: 0.1,

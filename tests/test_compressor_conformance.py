@@ -48,7 +48,7 @@ from repro.core import adaptk, codec, compressors
 from repro.core.compression import CompressionConfig
 from repro.core.compressors import get_compressor
 from repro.core.error_feedback import compress_with_ef, supports_fused
-from repro.dist import aggregate, compat
+from repro.dist import aggregate
 from repro.dist.layout import (build_chunk_plan, build_layout, chunk_view,
                                leaf_key_salt, pack_grads)
 from repro.serve import (DELTA, RESYNC, apply_message, init_publisher_state,
@@ -72,8 +72,10 @@ SEEDS = st.integers(0, 2**31 - 1)
 # the k == 1 and k == d corners
 GEOMS = ((16, 1), (33, 4), (96, 96), (257, 5), (1024, 48))
 GEOM = st.sampled_from(GEOMS)
-SCALES = st.floats(min_value=1e-3, max_value=1e3, width=32,
-                   allow_nan=False, allow_infinity=False)
+# width=32 bounds must be float32-representable: 1e-3 is not, so the
+# lower bound is its nearest float32
+SCALES = st.floats(min_value=float(np.float32(1e-3)), max_value=1e3,
+                   width=32, allow_nan=False, allow_infinity=False)
 
 
 def _key_for(spec, seed):
@@ -455,10 +457,10 @@ def test_globalk_allocation_single_device():
             state, [sigs[0], sigs[1]], [sqs[0], sqs[1]], dims, ratio,
             pol, jnp.int32(0), lo, hi, ("data",))
 
-    run = jax.jit(compat.shard_map(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P()), out_specs=(P(), P(), P()),
-        axis_names={"data"}))
+        axis_names={"data"}, check_vma=False))
 
     state = adaptk.init_controller_state(len(dims), global_k=True)
     sigs = jnp.asarray([4.0, 1.2], jnp.float32)

@@ -131,16 +131,17 @@ def test_fused_fewer_passes():
     with count_passes() as pu:
         unfused_compress_ef(g, e, "gaussiank", 200)
     assert pf.total() < pu.total(), (pf.records, pu.records)
-    # the TPU 3-pass claim is a property of the mosaic lowering (its
-    # sequential grid carries the residual write inside the compaction
-    # sweep), so the backend is pinned — under REPRO_KERNEL_BACKEND=
-    # triton the default resolution would pick the 4-pass GPU shape
+    # the TPU 3-pass claim is a property of the sequential kernel shape
+    # (its grid carries the residual write inside the compaction sweep),
+    # which the interpreter runs here with the mosaic fusions on; the
+    # backend is pinned — under REPRO_KERNEL_BACKEND=triton the default
+    # resolution would pick the 4-pass GPU shape
     with count_passes() as pf2:
-        fused_compress_ef(g, e, "gaussiank", 200, backend="mosaic",
+        fused_compress_ef(g, e, "gaussiank", 200, backend="interpret",
                           fuse_operands=True, write_resid=True)
     assert pf2.total() == 3, pf2.records     # the TPU-shape 3-pass claim
     with count_passes() as ph:
-        fused_compress_ef(g, e, "histk", 200, backend="mosaic",
+        fused_compress_ef(g, e, "histk", 200, backend="interpret",
                           fuse_operands=True, write_resid=True)
     assert ph.total() == 2, ph.records
     # the triton lowering splits compact/residual into two passes (the

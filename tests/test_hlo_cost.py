@@ -101,7 +101,7 @@ def _trace_chunked(params, strategy, n_chunks, world=4):
 
     from repro.core import get_compressor
     from repro.core.compression import CompressionConfig
-    from repro.dist import aggregate, compat
+    from repro.dist import aggregate
     from repro.dist.layout import build_chunk_plan, build_layout
 
     spec = get_compressor("topk")
@@ -109,7 +109,7 @@ def _trace_chunked(params, strategy, n_chunks, world=4):
     plan = build_chunk_plan(layout, n_chunks)
     grads = jax.tree.map(jnp.zeros_like, params)
     flat = jnp.zeros((layout.flat_size,))
-    mesh = AbstractMesh((("data", world), ("model", 1)))
+    mesh = AbstractMesh((world, 1), ("data", "model"))
     config = CompressionConfig(compressor="topk", ratio=0.05,
                                strategy=strategy, backend="reference")
 
@@ -118,9 +118,9 @@ def _trace_chunked(params, strategy, n_chunks, world=4):
             g, e, layout, plan, config, ("data",), "model",
             jax.random.PRNGKey(0), world=world).agg
 
-    sm = compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=P(), axis_names={"data"},
-                          check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(), axis_names={"data"},
+                       check_vma=False)
     return count_wire_collectives(jax.make_jaxpr(sm)(grads, flat))
 
 

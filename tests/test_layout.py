@@ -16,13 +16,14 @@ from jax.sharding import PartitionSpec as P
 from repro.core import codec, get_compressor
 from repro.core.adaptk import make_policy
 from repro.core.compression import CompressionConfig
-from repro.dist import aggregate, compat
+from repro.dist import aggregate
 from repro.dist.layout import (build_chunk_plan, build_layout, chunk_view,
                                collective_count, flat_dims, leaf_key_salt,
                                pack_grads, pack_residual_arrays,
                                unpack_residual_arrays, unpack_tree,
                                validate_chunk_plan)
 from repro.launch.hlo_cost import count_wire_collectives
+from repro.launch.mesh import make_mesh
 
 MSIZE, RATIO = 2, 0.05
 
@@ -222,7 +223,7 @@ def test_leaf_salts_stable_under_insertion():
 def test_per_leaf_randk_unchanged_by_unrelated_leaf():
     """aggregate_compressed with a keyed compressor selects the same
     coordinates for leaf "a" whether or not an unrelated leaf exists."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def run(params):
         grads = _grads(params)
@@ -233,9 +234,9 @@ def test_per_leaf_randk_unchanged_by_unrelated_leaf():
                 g, e, CompressionConfig(compressor="randk", ratio=RATIO),
                 ("data",), "model", MSIZE, jax.random.PRNGKey(7), world=1)
             return res.agg
-        sm = compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                              out_specs=P(), axis_names={"data"},
-                              check_vma=False)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                           out_specs=P(), axis_names={"data"},
+                           check_vma=False)
         return jax.jit(sm)(grads, resid)
 
     small = run(_params())
@@ -363,7 +364,7 @@ def _run_both(params, strategy, *, mesh_shape=(1, 1),
     grads = _grads(params)
     resid = _resid_tree(params)
     r2 = _resid_tree(params, seed=11, scale=5e-4) if with_r2 else None
-    mesh = jax.make_mesh(mesh_shape, axes_names)
+    mesh = make_mesh(mesh_shape, axes_names)
     data_axes = tuple(a for a in axes_names if a != "model")
     config = CompressionConfig(
         compressor=name, ratio=RATIO, strategy=strategy,
@@ -386,14 +387,14 @@ def _run_both(params, strategy, *, mesh_shape=(1, 1),
                 + ((res.resid2,) if r2s else ()))
 
     n_out = 4 if with_r2 else 3
-    sm1 = compat.shard_map(per_leaf, mesh=mesh,
-                           in_specs=(P(),) * (2 + with_r2),
-                           out_specs=(P(),) * n_out,
-                           axis_names=set(data_axes), check_vma=False)
-    sm2 = compat.shard_map(bucketed, mesh=mesh,
-                           in_specs=(P(),) * (2 + with_r2),
-                           out_specs=(P(),) * n_out,
-                           axis_names=set(data_axes), check_vma=False)
+    sm1 = jax.shard_map(per_leaf, mesh=mesh,
+                        in_specs=(P(),) * (2 + with_r2),
+                        out_specs=(P(),) * n_out,
+                        axis_names=set(data_axes), check_vma=False)
+    sm2 = jax.shard_map(bucketed, mesh=mesh,
+                        in_specs=(P(),) * (2 + with_r2),
+                        out_specs=(P(),) * n_out,
+                        axis_names=set(data_axes), check_vma=False)
     args1 = (grads, resid) + ((r2,) if with_r2 else ())
     flat_e = _flatten_resid(layout, resid)
     args2 = (grads, flat_e) + (
@@ -441,7 +442,7 @@ def test_bucketed_runtime_grad_dtype_wins_over_layout_dtype():
     layout = build_layout(params16, MSIZE, RATIO, spec)
     grads = _grads(_params())          # f32, same shapes
     resid = _resid_tree(_params())
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     config = CompressionConfig(compressor="topk", ratio=RATIO,
                                backend="reference")
@@ -458,12 +459,12 @@ def test_bucketed_runtime_grad_dtype_wins_over_layout_dtype():
             jax.random.PRNGKey(7), world=1)
         return res.agg, res.metrics
 
-    sm2 = compat.shard_map(bucketed, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()), axis_names={"data"},
-                           check_vma=False)
-    sm1 = compat.shard_map(per_leaf, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()), axis_names={"data"},
-                           check_vma=False)
+    sm2 = jax.shard_map(bucketed, mesh=mesh, in_specs=(P(), P()),
+                        out_specs=(P(), P()), axis_names={"data"},
+                        check_vma=False)
+    sm1 = jax.shard_map(per_leaf, mesh=mesh, in_specs=(P(), P()),
+                        out_specs=(P(), P()), axis_names={"data"},
+                        check_vma=False)
     agg_b, m_b = jax.jit(sm2)(grads, _flatten_resid(layout, resid))
     agg_p, m_p = jax.jit(sm1)(grads, resid)
     for a, b in zip(jax.tree.leaves(agg_p), jax.tree.leaves(agg_b)):
@@ -521,9 +522,9 @@ def _trace_collectives(params, strategy, bucketed, mesh,
                 **kw)
         return res.agg
 
-    sm = compat.shard_map(body, mesh=mesh,
-                          in_specs=(P(),) * (2 + with_r2), out_specs=P(),
-                          axis_names=set(data_axes), check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(),) * (2 + with_r2), out_specs=P(),
+                       axis_names=set(data_axes), check_vma=False)
     args = ((grads, flat) if bucketed else (grads, resid))
     args += ((r2_flat if bucketed else r2_tree,) if with_r2 else ())
     return count_wire_collectives(jax.make_jaxpr(sm)(*args))
@@ -534,8 +535,8 @@ def test_jaxpr_one_collective_per_level_independent_of_leaf_count():
     wire level per step (log2(W) ppermute rounds total for gTop-k), for
     any leaf count.  One codec pair == 2 array collectives (values +
     indices)."""
-    mesh = AbstractMesh((("data", 4), ("model", MSIZE)))
-    pod_mesh = AbstractMesh((("pod", 2), ("data", 2), ("model", MSIZE)))
+    mesh = AbstractMesh((4, MSIZE), ("data", "model"))
+    pod_mesh = AbstractMesh((2, 2, MSIZE), ("pod", "data", "model"))
     for params in (_params(), _params(extra=True)):
         L = len(jax.tree.leaves(params))
         # allgather: 1 message (2 array collectives) vs L
@@ -559,7 +560,7 @@ def test_jaxpr_one_collective_per_level_independent_of_leaf_count():
 
 
 def test_jaxpr_adaptive_bucketed_still_single_collective():
-    mesh = AbstractMesh((("data", 4), ("model", MSIZE)))
+    mesh = AbstractMesh((4, MSIZE), ("data", "model"))
     c = _trace_collectives(_params(), "allgather", True, mesh,
                            density_policy=make_policy("variance"))
     assert (c["all_gather"], c["ppermute"]) == (2, 0), c
@@ -580,7 +581,7 @@ def test_train_step_bucketed_matches_per_leaf():
     # at the mesh's model size (1); the multi-shard runs live in the
     # slow job (tests/_dist_check.py bucketed)
     layout = build_layout(params, 1, RATIO, spec)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd_momentum(0.9)
 
     def loss_fn(p, b):
@@ -624,7 +625,7 @@ def test_train_step_chunked_matches_unchunked():
     spec = get_compressor("topk")
     params = _grads(_params(), seed=4)   # nonzero params: real gradients,
     layout = build_layout(params, 1, RATIO, spec)   # non-degenerate top-k
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd_momentum(0.9)
 
     def loss_fn(p, b):
@@ -658,7 +659,7 @@ def test_train_step_chunked_needs_bucketed_pipeline():
     from repro.train import make_train_step
 
     params = _params()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd_momentum(0.9)
     layout = build_layout(params, 1, RATIO, get_compressor("topk"))
     sparse2 = CompressionConfig(compressor="topk", ratio=RATIO, chunks=2)
@@ -679,7 +680,7 @@ def test_train_step_layout_mismatch_fails_loudly():
     from repro.train import init_train_state, make_train_step
 
     params = _params()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd_momentum(0.9)
     layout1 = build_layout(params, 1, RATIO, get_compressor("topk"))
     topk = CompressionConfig(compressor="topk", ratio=RATIO)

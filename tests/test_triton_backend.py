@@ -17,7 +17,7 @@ import pytest
 from repro.core import codec
 from repro.core.compression import CompressionConfig
 from repro.core.compressors import get_compressor
-from repro.dist import aggregate, compat
+from repro.dist import aggregate
 from repro.dist.layout import build_layout, pack_residual_arrays
 from repro.kernels.ef_fused import (count_passes, fused_compress_ef,
                                     tuning, use_backend)
@@ -27,6 +27,7 @@ from repro.kernels.ef_fused.segmented import (rows_compress_ef,
                                               segmented_compress_ef)
 from repro.kernels.ef_fused.tree_count import tree_count
 from repro.kernels.gaussian_topk.threshold_compact import SENTINEL
+from repro.launch.mesh import make_mesh
 
 BLOCK = 2048
 FUSED = ("gaussiank", "gaussiank2", "histk")
@@ -219,7 +220,7 @@ def test_aggregate_bucketed_under_triton_context():
         layout, [np.asarray(x) for x in jax.tree.leaves(resid)]))
     config = CompressionConfig(compressor="gaussiank", ratio=0.05,
                                backend="fused")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def bucketed(g, e):
         res = aggregate.aggregate_bucketed(
@@ -227,9 +228,9 @@ def test_aggregate_bucketed_under_triton_context():
             jax.random.PRNGKey(7), world=1)
         return res.agg, res.resid, res.metrics
 
-    sm = compat.shard_map(bucketed, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=(P(), P(), P()), axis_names={"data"},
-                          check_vma=False)
+    sm = jax.shard_map(bucketed, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P(), P()), axis_names={"data"},
+                       check_vma=False)
     out_ref = jax.jit(sm)(grads, flat_e)
     with use_backend("triton"):
         out_tri = jax.jit(sm)(grads, flat_e)

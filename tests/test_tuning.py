@@ -3,7 +3,8 @@
 Pins the ISSUE 10 acceptance rules:
 
 * platform matrix — ``resolve_backend(None)`` picks mosaic on TPU,
-  triton on GPU, the interpreter on CPU; an explicit ``backend=``
+  triton on GPU, the interpreter on CPU, and raises on any other
+  platform; mosaic never runs interpreted; an explicit ``backend=``
   always wins; the legacy ``interpret=`` bool still works behind
   exactly ONE ``DeprecationWarning`` per process;
 * per-dtype block minima — derived from (backend, dtype): mosaic one
@@ -97,9 +98,27 @@ def test_exec_interpret_matrix():
     assert exec_interpret("interpret", "tpu")
     assert exec_interpret("interpret", "gpu")
     assert not exec_interpret("mosaic", "tpu")
-    assert exec_interpret("mosaic", "cpu")      # emulated off-platform
+    assert not exec_interpret("mosaic", "cpu")  # compiles or raises
     assert not exec_interpret("triton", "gpu")
     assert exec_interpret("triton", "cpu")      # the CI smoke leg
+
+
+def test_unknown_platform_raises(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(ValueError, match="no kernel backend"):
+        resolve_backend(None, None)
+    with pytest.raises(ValueError, match="no kernel backend"):
+        tuning.default_backend("metal")
+
+
+def test_mosaic_off_tpu_raises_instead_of_interpreting():
+    """backend="mosaic" lowers for the TPU only: on the CPU it fails to
+    compile rather than running the kernels under the interpreter."""
+    import jax.numpy as jnp
+    from repro.kernels.ef_fused import fused_compress_ef
+    g = jnp.ones((4096,), jnp.float32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        fused_compress_ef(g, None, "gaussiank", 40, backend="mosaic")
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,9 @@ def _free_port() -> int:
 def _env(extra_xla: str = ""):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # the children are a CPU federation rehearsal run side by side: none
+    # of them may reach for an accelerator, which one process owns
+    env["JAX_PLATFORMS"] = "cpu"
     if extra_xla:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + extra_xla).strip()
     return env
