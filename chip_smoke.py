@@ -148,8 +148,7 @@ def _train(argv, label):
     for rec in history:
         comm = ("" if rec["comm_frac"] is None
                 else f" comm_frac={rec['comm_frac']:.6f}")
-        _say(f"{label} step {rec['step']} loss={rec['loss']:.6f}{comm} "
-             f"wall={rec['seconds']:.3f}s")
+        _say(f"{label} step {rec['step']} loss={rec['loss']:.6f}{comm}")
     _check(all(math.isfinite(rec["loss"]) for rec in history),
            f"{label}: non-finite loss")
     return history

@@ -363,6 +363,7 @@ def allocate(K_total, weights, lo, hi, *, bisect_iters: int = 48):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("ef.select")
 def select_dynamic(spec: CompressorSpec, u: jax.Array, k, k_cap: int,
                    key=None):
     """Fixed-capacity selection with a *traced* per-step budget ``k``.

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 SENTINEL = -1
 
 
+@jax.named_scope("ef.compact")
 def compact_by_mask(u: jax.Array, mask: jax.Array, k_cap: int):
     """Compact the masked elements of ``u`` into a fixed ``(k_cap,)`` buffer.
 

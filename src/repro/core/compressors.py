@@ -42,12 +42,16 @@ class CompressorSpec(NamedTuple):
     select: Callable  # (u, k, key) -> (values, indices)
     k_cap: Callable[[int, int], int]  # (k, d) -> capacity
     needs_key: bool = False
+    # runs Algorithm 1's refinement toward the band [2k/3, 4k/3]: its
+    # kept count can end under the band (``ef_leaves_under_band``)
+    banded: bool = False
 
 
 # ---------------------------------------------------------------------------
 # Exact Top-k
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ef.select")
 def topk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None):
     """Exact ``Top_k``: the k largest |u| coordinates (paper Eq. 3 context)."""
     _, idx = jax.lax.top_k(jnp.abs(u), k)
@@ -59,6 +63,7 @@ def topk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None):
 # Rand-k
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ef.select")
 def randk_select(u: jax.Array, k: int, key: jax.Array):
     """``Rand_k``: k uniform indices without replacement (Gumbel-top-k)."""
     z = jax.random.uniform(key, u.shape)
@@ -104,6 +109,7 @@ def gaussian_threshold(u: jax.Array, k: int, refine_iters: int = 4,
     return thres
 
 
+@jax.named_scope("ef.select")
 def gaussiank_select(u: jax.Array, k: int, key: Optional[jax.Array] = None,
                      refine_iters: int = 4, two_sided: bool = False):
     """``Gaussian_k`` (paper Algorithm 1): threshold + fixed-capacity compact."""
@@ -133,6 +139,7 @@ def _strided_sample(key, d: int, s: int) -> jax.Array:
     return (offset + stride * jnp.arange(s, dtype=jnp.int32)) % d
 
 
+@jax.named_scope("ef.select")
 def dgck_select(u: jax.Array, k: int, key: jax.Array, sample_ratio: float = 0.01):
     """``DGC_k``: estimate threshold from a random sample, gather candidates
     above it, then exact top-k among the candidates (two small top-k calls
@@ -169,6 +176,7 @@ def rtopk_sample_size(k: int, d: int, sample_mult: float = 4.0) -> int:
     return max(k, min(d, int(math.ceil(sample_mult * k))))
 
 
+@jax.named_scope("ef.select")
 def rtopk_select(u: jax.Array, k: int, key: jax.Array,
                  sample_mult: float = 4.0):
     """``rTop_k`` (Barnes et al. 2020, arXiv:2005.10761): draw a random
@@ -199,6 +207,7 @@ def rtopk_cap(k: int, d: int) -> int:
 # Trimmed-k (RedSync, Fang et al. 2019)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ef.select")
 def trimmedk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None,
                     iters: int = 16):
     """``Trimmed_k``: bisect a threshold between mean(|u|) and max(|u|).
@@ -229,6 +238,7 @@ def trimmedk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None,
 # Registry
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ef.select")
 def histk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None):
     """``Hist_k`` (beyond-paper): one-pass exponent-histogram threshold +
     blocked compaction — 2 passes over u total, no refinement loop.  Reuses
@@ -240,9 +250,11 @@ def histk_select(u: jax.Array, k: int, key: Optional[jax.Array] = None):
 _REGISTRY = {
     "topk": CompressorSpec("topk", topk_select, lambda k, d: k),
     "randk": CompressorSpec("randk", randk_select, lambda k, d: k, needs_key=True),
-    "gaussiank": CompressorSpec("gaussiank", gaussiank_select, gaussiank_cap),
+    "gaussiank": CompressorSpec("gaussiank", gaussiank_select, gaussiank_cap,
+                                banded=True),
     "gaussiank2": CompressorSpec(
-        "gaussiank2", partial(gaussiank_select, two_sided=True), gaussiank_cap),
+        "gaussiank2", partial(gaussiank_select, two_sided=True), gaussiank_cap,
+        banded=True),
     "dgck": CompressorSpec("dgck", dgck_select, lambda k, d: k, needs_key=True),
     "trimmedk": CompressorSpec(
         "trimmedk", trimmedk_select, lambda k, d: min(d, 2 * k)),

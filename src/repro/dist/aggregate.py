@@ -210,6 +210,7 @@ def _decode_rows(values: jax.Array, indices: jax.Array, d_row: int,
         lambda v, i: codec.decode(v.astype(dtype), i, d_row))(values, indices)
 
 
+@jax.named_scope("ef.residual")
 def _wire_cast_fixup(values, indices, new_e_rows, codec_dtype):
     """Down-cast wire values and fold the cast error into the residual
     with a k-sized scatter-add (``e' += decode(values − cast(values))``)
@@ -235,6 +236,7 @@ def _compress_rows_fused(g_rows: jax.Array, e_rows: jax.Array,
     return values, indices, new_e_rows
 
 
+@jax.named_scope("ef.residual")
 def _compress_rows(g_rows: jax.Array, e_rows: jax.Array,
                    spec: CompressorSpec, k_row: int, k_cap: int, key, *,
                    codec_dtype=None, momentum: float = 0.0, v_rows=None,
@@ -346,6 +348,7 @@ def pass_a_stats_rows(g_rows: jax.Array, e_rows: jax.Array, name: str,
     return None, (jnp.sum(u), jnp.sum(u * u), jnp.max(jnp.abs(u)))
 
 
+@jax.named_scope("ef.residual")
 def _compress_rows_dynamic(g_rows: jax.Array, e_rows: jax.Array,
                            spec: CompressorSpec, k, k_cap: int, key, *,
                            codec_dtype=None, backend: str = "auto",
@@ -472,6 +475,7 @@ def gtopk_round_plan(axis_sizes):
     return plan
 
 
+@jax.named_scope("wire")
 def _gtopk_reduce_rounds(values, indices, axes, d_row: int, encode,
                          dtype=jnp.float32):
     """The recursive-doubling XOR-merge loop shared by both dispatch
@@ -572,12 +576,14 @@ def gtopk_simulate(partials, k_cap: int, codec_dtype=None):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("wire")
 def aggregate_dense(grads, data_axes):
     """Dense-SGD baseline: plain mean over the data axes."""
     axes = tuple(data_axes)
     return jax.tree.map(lambda g: jax.lax.pmean(g, axes), grads)
 
 
+@jax.named_scope("wire")
 def _gather_mean(values, indices, axis, n: int, d_row: int, dtype):
     """All-gather fixed-capacity pairs over ``axis`` and decode-average.
 
@@ -920,7 +926,6 @@ def _aggregate_compressed(grads, resid, config: CompressionConfig,
         # signal (budget exactness: k_total == clip of the configured
         # budget into the policy's [floor, ceiling] sums)
         metrics["k_total"] = K_eff.astype(jnp.float32)
-        metrics["density_budget"] = K_eff.astype(jnp.float32) / d_total
     new_resid = treedef.unflatten(new_e_leaves)
     new_resid2 = (treedef.unflatten(new_r2_leaves)
                   if resid2 is not None else None)
@@ -1003,13 +1008,53 @@ def bucket_compress(G: jax.Array, E: jax.Array, layout: BucketLayout,
             if nv is not None:
                 new_v_blocks.append(nv)
 
-    values = jnp.concatenate(vals, axis=1)
-    indices = jnp.concatenate(idcs, axis=1)
-    new_E = jnp.concatenate([blk.astype(E.dtype) for blk in new_e_blocks],
-                            axis=1)
-    new_V = (jnp.concatenate([blk.astype(E.dtype) for blk in new_v_blocks],
-                             axis=1) if new_v_blocks else None)
+    with jax.named_scope("bucket.pack"):
+        values = jnp.concatenate(vals, axis=1)
+        indices = jnp.concatenate(idcs, axis=1)
+        new_E = jnp.concatenate(
+            [blk.astype(E.dtype) for blk in new_e_blocks], axis=1)
+        new_V = (jnp.concatenate(
+            [blk.astype(E.dtype) for blk in new_v_blocks], axis=1)
+            if new_v_blocks else None)
     return values, indices, new_E, new_V
+
+
+def _segment_outcomes(indices: jax.Array, layout: BucketLayout,
+                      spec: CompressorSpec, k_alloc=None):
+    """Algorithm 1's outcome on one worker's ``(model_size,
+    k_cap_total)`` wire block, read per leaf segment at its static
+    column offset: ``(at_cap, under_band)``, float counts of segments.
+
+    ``at_cap``: segments whose kept count equals their capacity
+    ``model_size * k_cap`` — the selection over-ran the codec and its
+    surplus (the highest indices) stayed in the residual.  Selectors
+    that always fill their capacity (topk, randk, rtopk, dgck) count
+    every segment here.  ``under_band``: for the compressors that run
+    Algorithm 1's refinement (``spec.banded``: gaussiank, gaussiank2;
+    not histk, whose one-pass threshold has no band), segments that
+    kept fewer than ``model_size * ceil(2 k_row / 3)`` — the refinement
+    ended under its accept band; 0 for the others.  ``k_alloc`` gives
+    the adaptive path's traced per-segment budgets."""
+    M = layout.model_size
+    banded = spec.banded
+    at_cap = under = jnp.zeros((), jnp.float32)
+    for si, s in enumerate(layout.segments):
+        kept = codec.nnz(indices[:, s.cap_off:s.cap_off + s.k_cap])
+        at_cap += (kept == M * s.k_cap).astype(jnp.float32)
+        if banded:
+            k_row = (s.k_row if k_alloc is None else
+                     jnp.clip((k_alloc[si] + M - 1) // M, 1, s.d_row))
+            under += (kept < M * ((2 * k_row + 2) // 3)).astype(jnp.float32)
+    return at_cap, under
+
+
+def _replicated_outcomes(nnz_local, d_total: int, at_cap, under, axes):
+    """``density``, ``ef_leaves_at_cap`` and ``ef_leaves_under_band``,
+    averaged over the data axes in one ``pmean``."""
+    density, at_cap, under = jax.lax.pmean(
+        jnp.stack([nnz_local / d_total, at_cap, under]), axes)
+    return {"density": density, "ef_leaves_at_cap": at_cap,
+            "ef_leaves_under_band": under}
 
 
 def aggregate_bucketed(grads, resid, layout: BucketLayout, config,
@@ -1039,7 +1084,9 @@ def aggregate_bucketed(grads, resid, layout: BucketLayout, config,
     built for this config's ``spec`` and density mode — validated
     loudly).  The legacy spelling (a ``CompressorSpec`` in the config
     slot + loose kwargs) forwards with a ``DeprecationWarning``.
-    Returns an :class:`AggregateResult` with flat-bucket residuals.
+    Returns an :class:`AggregateResult` with flat-bucket residuals; its
+    metrics add ``ef_leaves_at_cap`` and ``ef_leaves_under_band``
+    (:func:`_segment_outcomes`, averaged over the data axes).
     """
     if isinstance(config, CompressorSpec):
         config = _config_from_legacy(
@@ -1117,6 +1164,7 @@ def _aggregate_bucketed(grads, resid, layout: BucketLayout,
         V=R2 if mc > 0.0 else None, backend=backend, k_alloc=k_alloc,
         seg_stats=seg_stats)
     nnz_local = codec.nnz(indices).astype(jnp.float32)
+    at_cap, under = _segment_outcomes(indices, layout, spec, k_alloc)
 
     # -- the wire: one collective per level --
     if gtopk:
@@ -1158,7 +1206,8 @@ def _aggregate_bucketed(grads, resid, layout: BucketLayout,
     bits_dense = float(sum(2 * g.size * jnp.dtype(g.dtype).itemsize * 8
                            for g in jax.tree.leaves(grads)))
     metrics = {
-        "density": jax.lax.pmean(nnz_local / layout.d_total, axes),
+        **_replicated_outcomes(nnz_local, layout.d_total, at_cap, under,
+                               axes),
         "density_cap": jnp.float32(
             M * layout.k_cap_total / layout.d_total),
         "comm_bits_sparse": jnp.float32(
@@ -1172,8 +1221,6 @@ def _aggregate_bucketed(grads, resid, layout: BucketLayout,
     }
     if adaptive:
         metrics["k_total"] = K_eff.astype(jnp.float32)
-        metrics["density_budget"] = (K_eff.astype(jnp.float32)
-                                     / layout.d_total)
     new_resid2 = new_R2.reshape(-1) if resid2 is not None else None
     return AggregateResult(agg, new_E.reshape(-1), new_resid2, new_adapt,
                            metrics)
@@ -1319,7 +1366,7 @@ def _aggregate_bucketed_chunked(grads, resid, layout: BucketLayout,
     # at the dataflow level; see DESIGN.md §11 for the CPU/interpret
     # caveat).
     means, new_E_blocks, new_R2_blocks = [], [], []
-    nnz_local = jnp.zeros((), jnp.float32)
+    nnz_local = at_cap = under = jnp.zeros((), jnp.float32)
     for c, (grp, view) in enumerate(zip(plan.groups, views)):
         ka = k_alloc[grp.seg_lo:grp.seg_hi] if adaptive else None
         values, indices, new_Ec, new_Vc = bucket_compress(
@@ -1327,6 +1374,8 @@ def _aggregate_bucketed_chunked(grads, resid, layout: BucketLayout,
             momentum=mc, V=R2s[c] if mc > 0.0 else None, backend=backend,
             k_alloc=ka, seg_stats=chunk_stats[c])
         nnz_local += codec.nnz(indices).astype(jnp.float32)
+        at_cap_c, under_c = _segment_outcomes(indices, view, spec, ka)
+        at_cap, under = at_cap + at_cap_c, under + under_c
 
         if gtopk:
             dense_sum, merge_drop = _gtopk_reduce_bucket(
@@ -1372,7 +1421,8 @@ def _aggregate_bucketed_chunked(grads, resid, layout: BucketLayout,
     bits_dense = float(sum(2 * g.size * jnp.dtype(g.dtype).itemsize * 8
                            for g in g_leaves))
     metrics = {
-        "density": jax.lax.pmean(nnz_local / layout.d_total, axes),
+        **_replicated_outcomes(nnz_local, layout.d_total, at_cap, under,
+                               axes),
         "density_cap": jnp.float32(
             M * layout.k_cap_total / layout.d_total),
         "comm_bits_sparse": jnp.float32(
@@ -1388,8 +1438,6 @@ def _aggregate_bucketed_chunked(grads, resid, layout: BucketLayout,
     }
     if adaptive:
         metrics["k_total"] = K_eff.astype(jnp.float32)
-        metrics["density_budget"] = (K_eff.astype(jnp.float32)
-                                     / layout.d_total)
     new_resid2 = (jnp.concatenate(
         [blk.astype(R2.dtype) for blk in new_R2_blocks], axis=1
         ).reshape(-1) if resid2 is not None else None)

@@ -389,6 +389,7 @@ def rebudget_layout(layout: BucketLayout, ratio: float,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("bucket.pack")
 def pack_grads(layout: BucketLayout, grads, dtype) -> jax.Array:
     """Pack a gradient pytree into the ``(model_size, d_row_total)``
     bucket: each leaf is flattened, zero-padded to ``d_pad``, cast to
@@ -409,6 +410,7 @@ def pack_grads(layout: BucketLayout, grads, dtype) -> jax.Array:
     return jnp.concatenate(blocks, axis=1)
 
 
+@jax.named_scope("bucket.unpack")
 def unpack_tree(layout: BucketLayout, bucket: jax.Array, treedef=None,
                 like=None):
     """Slice the ``(model_size, d_row_total)`` bucket back into the leaf

@@ -180,6 +180,7 @@ def _compact_residual_triton(operands, t, *, nblocks, block, bcap, k_cap,
     in_specs = [scalar_spec] + [data_spec] * len(operands)
     vals, offs, cnts = pl.pallas_call(
         functools.partial(_stage_kernel, has_e=has_e, bcap=bcap),
+        name="compact_residual_stage",
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=[
@@ -206,6 +207,7 @@ def _compact_residual_triton(operands, t, *, nblocks, block, bcap, k_cap,
         newe = pl.pallas_call(
             functools.partial(_resid_kernel, has_e=has_e, bcap=bcap,
                               k_cap=k_cap),
+            name="compact_residual_resid",
             grid=(nblocks,),
             in_specs=resid_in_specs,
             out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
@@ -275,6 +277,7 @@ def compact_residual(g2d: jax.Array, e2d: jax.Array | None,
                              group=group)
     outs = pl.pallas_call(
         kern,
+        name="compact_residual",
         grid=(steps,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [data_spec] * len(data),

@@ -172,6 +172,7 @@ def fused_moments(g2d: jax.Array, e2d: jax.Array | None = None, *,
                                    sub=sub, rows=rows))
     outs = pl.pallas_call(
         kern,
+        name="fused_moments",
         grid=(nblocks // group,),
         in_specs=[data_spec] * len(operands),
         out_specs=out_specs,

@@ -138,6 +138,7 @@ def _replay_refinement(heap: jax.Array, counts: jax.Array, k: int,
     return heap[idx]
 
 
+@jax.named_scope("ef.select")
 def _gaussian_threshold_fused(g2d, e2d, d: int, k, *, block: int,
                               refine_iters: int, two_sided: bool,
                               kcfg: "tuning.KernelConfig",
@@ -164,6 +165,7 @@ def _gaussian_threshold_fused(g2d, e2d, d: int, k, *, block: int,
     return _replay_refinement(heap, counts, k, refine_iters)
 
 
+@jax.named_scope("ef.select")
 def _hist_threshold_fused(g2d, e2d, d: int, k, pad: int, *, block: int,
                           kcfg: "tuning.KernelConfig",
                           interpret: bool, hist=None) -> jax.Array:
@@ -217,6 +219,7 @@ def _resolve(g, e, name, k, k_cap, block, stats_block, bcap, interpret,
     return d, k_cap, block, stats_block, bcap, cfg
 
 
+@jax.named_scope("ef.select")
 def fused_pass_a(g: jax.Array, e: jax.Array | None, name: str, *,
                  stats_block: int | None = None,
                  interpret: bool | None = None,
@@ -349,30 +352,34 @@ def fused_compress_ef(g: jax.Array, e: jax.Array | None, name: str, k,
             moments=None if stats is None else stats[:2])
     thres = jnp.maximum(jnp.asarray(thres, jnp.float32), 0.0)
 
-    a_c = _pad2d(a, block)[0]
-    b_c = _pad2d(b, block)[0] if b is not None else None
-    vals, offs, cnts, newe = compact_residual(
-        a_c, b_c, thres, bcap=bcap, k_cap=k_cap, block=block,
-        out_dtype=jnp.dtype(out_dtype).name, with_resid=write_resid,
-        backend=kbackend, num_warps=cfg.num_warps,
-        num_stages=cfg.num_stages, interpret=interp)
-    if write_resid and kbackend == "triton":
-        # the Triton lowering splits compaction and residual into two
-        # race-free sweeps (see compact_residual) — charge both
-        passes.record("compact", 1)
-        passes.record("residual_write", 1)
-    else:
-        passes.record("compact+residual" if write_resid else "compact", 1)
-    values, indices = assemble_staging(vals, offs, cnts, d, k_cap,
-                                       block=block, out_dtype=out_dtype)
-    if write_resid:
-        new_e = newe.reshape(-1)[:d]
-    else:
-        # wire values are exact u elements, so zeroing them IS u − decode
-        u = a if b is None else a + b
-        safe = jnp.where(indices == codec.SENTINEL, d, indices)
-        new_e = u.at[safe].set(0.0, mode="drop")
-        passes.record("residual_scatter", 1)
+    # pass B (and its residual write) is the compaction's scope
+    with jax.named_scope("ef.compact"):
+        a_c = _pad2d(a, block)[0]
+        b_c = _pad2d(b, block)[0] if b is not None else None
+        vals, offs, cnts, newe = compact_residual(
+            a_c, b_c, thres, bcap=bcap, k_cap=k_cap, block=block,
+            out_dtype=jnp.dtype(out_dtype).name, with_resid=write_resid,
+            backend=kbackend, num_warps=cfg.num_warps,
+            num_stages=cfg.num_stages, interpret=interp)
+        if write_resid and kbackend == "triton":
+            # the Triton lowering splits compaction and residual into two
+            # race-free sweeps (see compact_residual) — charge both
+            passes.record("compact", 1)
+            passes.record("residual_write", 1)
+        else:
+            passes.record("compact+residual" if write_resid else "compact",
+                          1)
+        values, indices = assemble_staging(vals, offs, cnts, d, k_cap,
+                                           block=block, out_dtype=out_dtype)
+        if write_resid:
+            new_e = newe.reshape(-1)[:d]
+        else:
+            # wire values are exact u elements, so zeroing them IS
+            # u − decode
+            u = a if b is None else a + b
+            safe = jnp.where(indices == codec.SENTINEL, d, indices)
+            new_e = u.at[safe].set(0.0, mode="drop")
+            passes.record("residual_scatter", 1)
     return values, indices, new_e
 
 

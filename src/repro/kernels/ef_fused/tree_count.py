@@ -94,6 +94,7 @@ def tree_count(g2d: jax.Array, e2d: jax.Array | None, thresholds: jax.Array,
     if backend == "triton":
         acc = pl.pallas_call(
             functools.partial(_partials_kernel, has_e=has_e, n_t=n_t),
+            name="tree_count_partials",
             grid=(nblocks,),
             in_specs=[pl.BlockSpec((1, 128), lambda i: (0, 0))] + data_specs,
             out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
@@ -104,6 +105,7 @@ def tree_count(g2d: jax.Array, e2d: jax.Array | None, thresholds: jax.Array,
         return jnp.sum(acc, axis=0)[:n_t]
     acc = pl.pallas_call(
         functools.partial(_kernel, has_e=has_e, n_t=n_t, sub=sub),
+        name="tree_count",
         grid=(nblocks // group,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + data_specs,
         out_specs=pl.BlockSpec((n_t * sub, LANES), lambda i: (0, 0)),

@@ -92,6 +92,7 @@ def assemble_staging(vals: jax.Array, offs: jax.Array, cnts: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("k_cap", "block", "bcap",
                                              "interpret"))
+@jax.named_scope("ef.compact")
 def select_by_threshold(u: jax.Array, thres: jax.Array, k_cap: int, *,
                         block: int = 2048, bcap: int | None = None,
                         interpret: bool = True):

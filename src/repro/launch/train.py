@@ -17,6 +17,9 @@ import os
 import sys
 import time
 
+# the steps [a, b) a --profile-dir trace holds, after the warm-up steps
+PROFILE_STEPS = (2, 5)
+
 
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
@@ -124,6 +127,14 @@ def parse_args(argv=None):
     ap.add_argument("--checkpoint", default="",
                     help="path to save the final state (npz)")
     ap.add_argument("--resume", default="")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a device trace of the steps "
+                         f"[{PROFILE_STEPS[0]}, {PROFILE_STEPS[1]}) that "
+                         "--steps reaches here (jax.profiler), "
+                         "with the compiled step's HLO text as "
+                         "step.hlo.txt; `python3 bench/scopes.py DIR "
+                         "--steps N` splits the step's device time by its "
+                         "named scopes (DESIGN.md §16)")
     return ap.parse_args(argv)
 
 
@@ -134,10 +145,18 @@ def main(argv=None):
 
 def train(argv=None) -> list:
     """Run the training loop; returns one record per logged step: ``step``,
-    ``loss``, ``comm_frac`` (None for dense) and ``seconds``, the host
-    wall time since the previous record (the first includes compiling).
-    """
+    ``loss``, ``comm_frac``, ``ef_leaves_at_cap`` and
+    ``ef_leaves_under_band``, each None where the step has no such
+    metric (``comm_frac`` for dense, the counters off the bucketed
+    pipeline).  The loop's host spans (``train.input``,
+    ``train.dispatch``, ``train.sync``) label a ``--profile-dir``
+    trace."""
     args = parse_args(argv)
+    prof_lo, prof_hi = PROFILE_STEPS[0], min(PROFILE_STEPS[1], args.steps)
+    if args.profile_dir and prof_hi <= prof_lo:
+        raise SystemExit(f"--profile-dir traces the steps [{prof_lo}, "
+                         f"{PROFILE_STEPS[1]}), after the warm-up: it needs "
+                         f"--steps > {prof_lo}, got {args.steps}")
     if args.host_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices}")
@@ -312,11 +331,21 @@ def train(argv=None) -> list:
         pub_key = jax.random.fold_in(jax.random.PRNGKey(args.seed), 0x9B)
         pub_bits, n_deltas, n_resyncs = 0, 0, 0
     history = []
-    t0 = t_last = time.time()
+    span = jax.profiler.TraceAnnotation
+    run = step
+    t0 = time.time()
     for i in range(args.steps):
-        batch = batch_for(cfg, i, global_batch=args.batch, seq_len=args.seq,
-                          seed=args.seed)
-        state, m = step(state, batch)
+        if args.profile_dir and i == prof_lo:
+            # the executable the traced steps run, whose text goes beside
+            # the trace: the jit's own for these inputs (the last step's
+            # shapes and shardings), so lowering it compiles nothing
+            run = step.lower(state, batch).compile()
+            jax.profiler.start_trace(args.profile_dir)
+        with span("train.input"):
+            batch = batch_for(cfg, i, global_batch=args.batch,
+                              seq_len=args.seq, seed=args.seed)
+        with span("train.dispatch"):
+            state, m = run(state, batch)
         if pub_state is not None and (i + 1) % args.publish_every == 0:
             pub_state, msg = publish(pub_state, state["params"], pub_layout,
                                      pub_config, pub_key,
@@ -327,7 +356,8 @@ def train(argv=None) -> list:
             else:
                 n_deltas += 1
         if i % args.log_every == 0 or i == args.steps - 1:
-            loss = float(m["loss"])
+            with span("train.sync"):
+                loss = float(m["loss"])
             t_now = time.time()
             comm, r = "", None
             if "comm_bits_sparse" in m:
@@ -337,6 +367,12 @@ def train(argv=None) -> list:
                 comm += f" coll={int(m['collectives_per_step'])}"
             if "k_total" in m:
                 comm += f" k_total={int(m['k_total'])}"
+            # Algorithm 1's outcome over the leaf segments (DESIGN.md §16)
+            outcome = {c: float(m[c]) if c in m else None
+                       for c in ("ef_leaves_at_cap", "ef_leaves_under_band")}
+            if outcome["ef_leaves_at_cap"] is not None:
+                comm += (f" at_cap={outcome['ef_leaves_at_cap']:g}"
+                         f" under_band={outcome['ef_leaves_under_band']:g}")
             if decision is not None:
                 # record the auto decision alongside the step metrics
                 comm += (f" tuner={decision.strategy}"
@@ -345,8 +381,16 @@ def train(argv=None) -> list:
                   f"lr={float(m['lr']):.4g}{comm} "
                   f"({t_now - t0:.1f}s)", flush=True)
             history.append({"step": i, "loss": loss, "comm_frac": r,
-                            "seconds": t_now - t_last})
-            t_last = t_now
+                            **outcome})
+        if args.profile_dir and i == prof_hi - 1:
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            with open(os.path.join(args.profile_dir, "step.hlo.txt"),
+                      "w") as f:
+                f.write(run.as_text())
+            run = step
+            print(f"profile of steps {prof_lo}:{prof_hi} -> "
+                  f"{args.profile_dir}")
     if pub_state is not None:
         print(f"published {n_deltas} deltas + {n_resyncs} resyncs "
               f"({pub_bits / 8 / 2 ** 20:.3f} MiB on the wire)")

@@ -204,15 +204,20 @@ def make_train_step(cfg, mesh, optimizer: Optimizer, lr_fn: Callable,
                 "density_policy=...) — without it the EMA would be "
                 "silently disabled")
         params = constrain_params(state["params"], "model", msize)
-        if seam is None:
-            grad_loss = loss
-        else:
-            # route params through the chunk seam so the backward pass
-            # hands each chunk group's cotangents over as one unit
-            def grad_loss(p, b):
+
+        def grad_loss(p, b):
+            if seam is not None:
+                # route params through the chunk seam so the backward
+                # pass hands each chunk group's cotangents over as one
+                # unit
                 leaves, ptd = jax.tree_util.tree_flatten(p)
-                return loss(jax.tree_util.tree_unflatten(
-                    ptd, list(seam(tuple(leaves)))), b)
+                p = jax.tree_util.tree_unflatten(
+                    ptd, list(seam(tuple(leaves))))
+            # forward ops read ".../jvp(model)/...", backward ones
+            # ".../transpose(jvp(model))/..." (DESIGN.md §16)
+            with jax.named_scope("model"):
+                return loss(p, b)
+
         (l, metrics), grads = jax.value_and_grad(grad_loss, has_aux=True)(
             params, batch)
         grads = constrain_params(grads, "model", msize)
@@ -254,7 +259,9 @@ def make_train_step(cfg, mesh, optimizer: Optimizer, lr_fn: Callable,
 
         lr = lr_fn(state["step"])
         agg = constrain_params(agg, "model", msize)
-        new_params, new_opt = optimizer.update(params, state["opt"], agg, lr)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(params, state["opt"], agg,
+                                                   lr)
         new_params = constrain_params(new_params, "model", msize)
         new_state = dict(state, params=new_params, opt=new_opt,
                          step=state["step"] + 1)

@@ -10,6 +10,8 @@ must hold the Mosaic kernels as ``tpu_custom_call``s.
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -120,3 +122,137 @@ def test_fused_pipeline_compiles(one_chip, name, passes, dtype, with_e):
     compiled = jax.jit(f).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == passes
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("name,kernels", [
+    ("gaussiank", ("compact_residual", "fused_moments", "tree_count")),
+    ("histk", ("compact_residual", "fused_moments"))])
+def test_fused_pipeline_kernels_are_named(one_chip, name, kernels):
+    """Each Mosaic kernel's instruction takes its ``pallas_call`` name,
+    by which a device trace names the op, and holds the scope of its
+    pass (DESIGN.md §16): passes A and A' select, pass B compacts."""
+    g = jax.ShapeDtypeStruct((D,), jnp.float32, sharding=one_chip)
+    e = jax.ShapeDtypeStruct((D,), jnp.float32, sharding=one_chip)
+
+    def f(g, e):
+        return fused_compress_ef(g, e, name, D // 1000, backend="mosaic")
+
+    text = jax.jit(f).lower(g, e).compile().as_text()
+    calls = [re.match(r'\s*(?:ROOT\s+)?%?([\w-]+)\.?\d*\s=.*op_name="([^"]*)"',
+                      line).groups()
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(n for n, _ in calls) == list(kernels)
+    for kernel, op_name in calls:
+        scope = "ef.compact" if kernel == "compact_residual" else "ef.select"
+        assert f"/{scope}/" in op_name, (kernel, op_name)
+
+
+# ---------------------------------------------------------------------------
+# the layers of the benchmark's stand-in step, as the chip's compiler
+# leaves them (DESIGN.md §16)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_step_v5e(one_chip):
+    """The compiled text of the CPU stand-in of the benchmark's cell
+    (``bench/tests/tiny.py``), compiled for the described chip, whose
+    compiler drops the ``op_name`` of the scatters it rewrites; with
+    the scopes ``bench/scopes.py`` reads from it."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from bench import scopes, system
+    from bench.tests import tiny
+    from repro.launch import mesh as launch_mesh
+
+    device = next(iter(one_chip.device_set))
+
+    def chip_mesh(shape, axes):
+        assert tuple(shape) == (1, 1)
+        return Mesh(np.array([device]).reshape(1, 1), tuple(axes),
+                    axis_types=(AxisType.Auto,) * len(axes))
+
+    cell = tiny.cell("stablelm_efjnp_1chip")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(launch_mesh, "make_mesh", chip_mesh)
+        sut = system.System(cell["model"], cell["job"], 2 ** 31 + 5)
+    state = jax.eval_shape(sut.new_state)
+    rep = NamedSharding(sut.mesh, P())
+    batch = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+        jax.eval_shape(sut.batch, 0))
+    text = sut.step.lower(state, batch).compile().as_text()
+    lines = {m.group("name"): line for line in text.splitlines()
+             for m in [scopes._INSTR.match(line)] if m}
+    return scopes._parse(text), scopes.hlo_scopes(text), lines
+
+
+def _timed(comps):
+    """The instructions a device trace times (those of computations no
+    fusion calls: the entry, loop bodies), each with what it runs: the
+    instructions of its fused computations, nested ones included."""
+    called = {i.calls for instrs, _ in comps.values() for i in instrs
+              if i.calls}
+
+    def inner(comp, depth=0):
+        for ins in comps[comp][0]:
+            yield ins
+            if ins.calls and depth < 8:
+                yield from inner(ins.calls, depth + 1)
+
+    for comp, (instrs, _) in comps.items():
+        if comp not in called:
+            for ins in instrs:
+                yield ins, list(inner(ins.calls)) if ins.calls else [ins]
+
+
+def _assigns(comps, line: str) -> bool:
+    """Whether a scatter's combiner returns the update (``.at[].set``,
+    the compaction's) rather than adding it (a decode's)."""
+    region = re.search(r"to_apply=%?([\w.\-]+)", line).group(1)
+    instrs, root = comps[region]
+    by_name = {i.name: i for i in instrs}
+    ins = by_name[root]
+    while ins.op in ("bitcast", "copy") and ins.operands:
+        ins = by_name[ins.operands[0]]
+    return ins.op == "parameter"
+
+
+@pytest.mark.parametrize("check", [
+    "compaction_scatters", "compaction_cumsum", "decode_scatters",
+    "model_not_ef"])
+def test_tiny_step_layers_on_v5e(tiny_step_v5e, check):
+    """Every scatter and cumsum of ``codec.compact_by_mask`` is read as
+    ``ef.compact``, those the compiler left without an ``op_name`` too;
+    no decode's scatter-add is; no model op is read as an EF layer
+    outside a mixed fusion, and an op of the model alone is read as
+    the model."""
+    comps, got, lines = tiny_step_v5e
+    found = []
+    for ins, inside in _timed(comps):
+        label = got.labels.get(ins.name)
+        assigns = [_assigns(comps, lines[i.name]) for i in inside
+                   if i.op == "scatter"]
+        if check == "compaction_scatters" and any(assigns):
+            found.append(got.rules.get(ins.name))
+            assert label == "ef.compact", (ins.name, label)
+        elif check == "compaction_cumsum" and any(
+                i.op == "reduce-window" for i in inside):
+            found.append(got.rules.get(ins.name))
+            assert label == "ef.compact", (ins.name, label)
+        elif check == "decode_scatters" and assigns and not any(assigns):
+            found.append(got.rules.get(ins.name))
+            assert label != "ef.compact", (ins.name, label)
+        elif check == "model_not_ef":
+            own = {i.own for i in inside if i.own}
+            if (label or "").startswith("ef.") and ins.name not in got.mixed:
+                assert not any(s.startswith("model") for s in own), ins.name
+            if own and all(s.startswith("model") for s in own):
+                found.append(got.rules.get(ins.name))
+                assert label.startswith("model"), (ins.name, label)
+    assert found
+    if check == "compaction_scatters":
+        # the case the rule is for: scatters without an op_name
+        assert "scatter_index" in found
