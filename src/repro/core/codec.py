@@ -22,6 +22,12 @@ The codec contract every producer/consumer relies on:
   indices win) and the surplus mass must stay in the caller's
   error-feedback residual via the conservation identity
   ``u == decode(encode(u)) + residual``.
+
+``compact_by_mask`` finds each of the ``k_cap`` slots by a binary
+search of ``cumsum(mask)`` and gathers its value, where
+``k_cap * ceil(log2(d + 1)) <= d``; denser selections scatter every
+element to its slot.  A TPU runs a scatter's ``d`` updates one at a
+time (DESIGN.md §3), so the search is the form sparse selections take.
 """
 from __future__ import annotations
 
@@ -29,6 +35,8 @@ import jax
 import jax.numpy as jnp
 
 SENTINEL = -1
+# entries of the cumsum per step of the search's coarse first rounds
+_STRIDE = 4096
 
 
 @jax.named_scope("ef.compact")
@@ -44,11 +52,63 @@ def compact_by_mask(u: jax.Array, mask: jax.Array, k_cap: int):
     Returns ``(values, indices)`` with sentinel padding: unused slots
     carry ``indices == SENTINEL`` and ``values == 0``.  Real indices are
     strictly increasing, hence duplicate-free.
+
+    Both forms start from ``pos = cumsum(mask) - 1``, each selected
+    element's slot.  Where the ``k_cap`` slots are few against ``d``
+    (``k_cap * ceil(log2(d + 1)) <= d``, a static rule on the shapes),
+    slot ``j``'s index is the first ``i`` with ``pos[i] >= j``: a
+    lower-bound binary search, each round a ``k_cap``-sized gather, its
+    first rounds on every ``_STRIDE``-th entry of ``pos`` and its last
+    ``log2(_STRIDE) + 1`` inside the one stride that holds the answer;
+    then one gather of the values.
+    Otherwise every element scatters to its slot (the unselected and
+    the surplus to a scratch slot cut off after): ``d`` writes, which a
+    TPU performs one at a time (DESIGN.md §3), so it is kept for dense
+    selections.  Both give the same bits.
     """
     d = u.shape[0]
     mask = mask.astype(jnp.int32)
     # position of each selected element in the compacted output
     pos = jnp.cumsum(mask) - 1
+    if k_cap * d.bit_length() <= d:  # bit_length: ceil(log2(d + 1))
+        return _compact_by_search(u, pos, k_cap)
+    return _compact_by_scatter(u, mask, pos, k_cap)
+
+
+def _compact_by_search(u: jax.Array, pos: jax.Array, k_cap: int):
+    d = u.shape[0]
+    slots = jnp.arange(k_cap, dtype=jnp.int32)
+    # the first rounds search every _STRIDE-th entry of pos, the rest
+    # search the one stride of pos that holds the answer
+    block = jnp.searchsorted(pos[_STRIDE - 1::_STRIDE], slots, side="left",
+                             method="scan").astype(jnp.int32)
+    lo = block * _STRIDE
+    at = _lower_bound(pos, slots, lo, jnp.minimum(lo + _STRIDE, d),
+                      min(_STRIDE, d).bit_length())
+    real = slots <= pos[-1]
+    indices = jnp.where(real, at, SENTINEL)
+    values = jnp.where(real, u[jnp.minimum(at, d - 1)],
+                       jnp.zeros((), u.dtype))
+    return values, indices
+
+
+def _lower_bound(a: jax.Array, q: jax.Array, lo: jax.Array, hi: jax.Array,
+                 rounds: int) -> jax.Array:
+    """The first ``i`` in ``[lo, hi)`` with ``a[i] >= q`` (``hi`` if
+    none), for a sorted ``a``, elementwise over ``q``; ``rounds`` must
+    be at least ``ceil(log2(hi - lo + 1))``."""
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2
+        left = a[jnp.minimum(mid, a.shape[0] - 1)] >= q
+        return jnp.where(left, lo, mid + 1), jnp.where(left, mid, hi)
+
+    return jax.lax.fori_loop(0, rounds, halve, (lo, hi))[1]
+
+
+def _compact_by_scatter(u: jax.Array, mask: jax.Array, pos: jax.Array,
+                        k_cap: int):
+    d = u.shape[0]
     keep = (mask == 1) & (pos < k_cap)
     # overflow / unselected elements all write to the scratch slot k_cap
     slot = jnp.where(keep, pos, k_cap)

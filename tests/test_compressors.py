@@ -84,6 +84,67 @@ def test_compact_by_mask_order_and_overflow():
     np.testing.assert_array_equal(np.asarray(v), [1, 3, 5])
 
 
+def _compact_by_scatter_oracle(u, mask, k_cap):
+    """The d-sized scatter form of ``compact_by_mask``: every element
+    writes to its slot ``cumsum(mask) - 1``, the unselected and the
+    surplus to a scratch slot ``k_cap`` that is cut off."""
+    d = u.shape[0]
+    mask = mask.astype(jnp.int32)
+    pos = jnp.cumsum(mask) - 1
+    slot = jnp.where((mask == 1) & (pos < k_cap), pos, k_cap)
+    values = jnp.zeros((k_cap + 1,), u.dtype).at[slot].set(u, mode="drop")
+    indices = jnp.full((k_cap + 1,), SENTINEL, jnp.int32).at[slot].set(
+        jnp.arange(d, dtype=jnp.int32), mode="drop")
+    return values[:k_cap], indices[:k_cap]
+
+
+# (d, k_cap, mask density); the search form runs where
+# k_cap * ceil(log2(d + 1)) <= d, the scatter form elsewhere
+_COMPACT_CASES = {
+    "search_sparse": (4096, 40, 0.005),
+    "search_overflow": (4096, 40, 0.05),
+    "search_k1": (4096, 1, 0.01),
+    "search_at_rule": (4096, 315, 0.1),
+    "scatter_past_rule": (4096, 316, 0.1),
+    "scatter_full_k": (1000, 1000, 0.5),
+    "search_empty": (2000, 30, 0.0),
+    "search_full": (2000, 30, 1.0),
+    "scatter_empty": (64, 64, 0.0),
+    "scatter_full": (64, 20, 1.0),
+    "search_d1": (1, 1, 1.0),
+    "search_strides": (40_000, 500, 0.01),
+    "search_strides_overflow": (40_000, 100, 0.01),
+}
+
+
+@pytest.mark.parametrize("rows", [None, 3], ids=["row", "vmap3"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(_COMPACT_CASES))
+def test_compact_by_mask_bits_match_scatter(case, dtype, rows):
+    """``compact_by_mask`` gives the scatter form's bytes, values and
+    indices, in both of its forms: index order, overflow keeping the
+    lowest indices, sentinel slots holding +0."""
+    d, k_cap, density = _COMPACT_CASES[case]
+    assert (k_cap * d.bit_length() <= d) == case.startswith("search")
+    rng = np.random.default_rng(list(_COMPACT_CASES).index(case))
+    shape = (rows or 1, d)
+    u = jnp.asarray(rng.normal(size=shape), dtype)
+    mask = jnp.asarray(rng.random(shape) < density)
+    if rows is None:
+        u, mask = u[0], mask[0]
+        got = codec.compact_by_mask(u, mask, k_cap)
+        want = _compact_by_scatter_oracle(u, mask, k_cap)
+    else:
+        got = jax.vmap(lambda a, m: codec.compact_by_mask(a, m, k_cap))(
+            u, mask)
+        want = jax.vmap(lambda a, m: _compact_by_scatter_oracle(
+            a, m, k_cap))(u, mask)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1), st.integers(10, 2000),
        st.integers(1, 50))

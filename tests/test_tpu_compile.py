@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import codec
 from repro.kernels.ef_fused import fused_compress_ef
 from repro.kernels.ef_fused.compact_residual import compact_residual
 from repro.kernels.ef_fused.fused_moments import fused_moments
@@ -147,6 +148,33 @@ def test_fused_pipeline_kernels_are_named(one_chip, name, kernels):
         assert f"/{scope}/" in op_name, (kernel, op_name)
 
 
+def test_jnp_compaction_at_lm_head_has_no_scatter(one_chip):
+    """The jnp Gaussian-k's compaction of the benchmark's LM head
+    (stablelm-2-1.6b: 100352 x 2048, k_cap = ceil(4k/3) at ratio 0.001)
+    searches and gathers: no d-sized scatter, which the chip runs an
+    element at a time, and no more temp memory than the scatter form."""
+    d, k_cap = 205_520_896, 274_028
+    u = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+
+    def compiled(compact):
+        return jax.jit(lambda u, t: compact(u, jnp.abs(u) > t)).lower(
+            u, t).compile()
+
+    def by_scatter(u, mask):
+        mask = mask.astype(jnp.int32)
+        return codec._compact_by_scatter(u, mask, jnp.cumsum(mask) - 1,
+                                         k_cap)
+
+    new = compiled(lambda u, mask: codec.compact_by_mask(u, mask, k_cap))
+    old = compiled(by_scatter)
+    ops = re.compile(r"= \S+ (scatter|gather)\(")
+    assert set(ops.findall(new.as_text())) == {"gather"}
+    assert "scatter" in ops.findall(old.as_text())
+    assert (new.memory_analysis().temp_size_in_bytes
+            <= old.memory_analysis().temp_size_in_bytes)
+
+
 # ---------------------------------------------------------------------------
 # the layers of the benchmark's stand-in step, as the chip's compiler
 # leaves them (DESIGN.md §16)
@@ -221,22 +249,25 @@ def _assigns(comps, line: str) -> bool:
 
 
 @pytest.mark.parametrize("check", [
-    "compaction_scatters", "compaction_cumsum", "decode_scatters",
+    "compaction_gathers", "compaction_cumsum", "decode_scatters",
     "model_not_ef"])
 def test_tiny_step_layers_on_v5e(tiny_step_v5e, check):
-    """Every scatter and cumsum of ``codec.compact_by_mask`` is read as
-    ``ef.compact``, those the compiler left without an ``op_name`` too;
-    no decode's scatter-add is; no model op is read as an EF layer
-    outside a mixed fusion, and an op of the model alone is read as
-    the model."""
+    """Every gather and cumsum of ``codec.compact_by_mask`` (the
+    search's rounds and the read of the values; the stand-in's leaves
+    all take the search) is read as ``ef.compact``, those the compiler
+    left without an ``op_name`` too; no decode's scatter-add is; no
+    model op is read as an EF layer outside a mixed fusion, and an op
+    of the model alone is read as the model."""
     comps, got, lines = tiny_step_v5e
     found = []
     for ins, inside in _timed(comps):
         label = got.labels.get(ins.name)
         assigns = [_assigns(comps, lines[i.name]) for i in inside
                    if i.op == "scatter"]
-        if check == "compaction_scatters" and any(assigns):
-            found.append(got.rules.get(ins.name))
+        if check == "compaction_gathers" and any(
+                i.op == "gather" and not (i.own or "").startswith("model")
+                for i in inside):
+            found.append(ins.name)
             assert label == "ef.compact", (ins.name, label)
         elif check == "compaction_cumsum" and any(
                 i.op == "reduce-window" for i in inside):
@@ -253,6 +284,12 @@ def test_tiny_step_layers_on_v5e(tiny_step_v5e, check):
                 found.append(got.rules.get(ins.name))
                 assert label.startswith("model"), (ins.name, label)
     assert found
-    if check == "compaction_scatters":
-        # the case the rule is for: scatters without an op_name
-        assert "scatter_index" in found
+    if check == "compaction_gathers":
+        # the search's rounds run in loop bodies, the read of the values
+        # outside them
+        instrs = [(comp, i) for comp, (ins, _) in comps.items() for i in ins]
+        comp_of = {i.name: comp for comp, i in instrs}
+        bodies = {re.search(r"body=%?([\w.\-]+)", lines[i.name]).group(1)
+                  for _, i in instrs if i.op == "while"}
+        in_loop = [comp_of[name] in bodies for name in found]
+        assert any(in_loop) and not all(in_loop), found
