@@ -45,18 +45,18 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("calibrate: no TPU", file=sys.stderr)
         return run.NO_DEVICE
-    model, job = cell["model"], cell["job"]
+    model, job, family = cell["model"], cell["job"], cell["family"]
     n = job["check_steps"]
-    names = reference.leaf_shapes(model)[0]
+    names = reference.leaf_shapes(family, model)[0]
     for n_seed, seed in enumerate(args.seeds):
         t = time.perf_counter()
-        ref = reference.first_steps(model, job, seed, n_steps=n)
+        ref = reference.first_steps(family, model, job, seed, n_steps=n)
         t_ref = time.perf_counter() - t
         faults = args.faults if n_seed < args.fault_seeds else []
         for fault in [None] + faults:
             t = time.perf_counter()
             if fault == harness.CONTROL:
-                prog = harness.control(model, job, seed)
+                prog = harness.control(cell, seed)
             else:
                 sut = system.System(model, job, seed, fault=fault)
                 state, prog, _ = harness.first_steps(sut, n)

@@ -8,20 +8,21 @@ window then goes on from the same state: steps are dispatched with at
 most ``DEPTH`` in flight and no host sync of the step just dispatched,
 and it ends on the completion of its last step.  Once it has closed and
 the peak memory is read, the program's state is freed and the reference
-runs the same first steps (``reference.first_steps``).
+runs the same first steps (``reference.first_steps``) with the
+model of the cell's family.  A traced run also splits the step's
+device time by the layers the program names (``bench/scopes.py``).
 """
 from __future__ import annotations
 
 import collections
 import gc
-import importlib.util
 import tempfile
 import time
 
 import jax
 import numpy as np
 
-from bench import compare, flops, reference, system, trace
+from bench import compare, flops, reference, scopes, spec, system, trace
 from bench.peaks import peaks_of
 
 DEPTH = 2            # steps in flight in the window
@@ -61,7 +62,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *, t0: float,
         fault: str | None = None) -> dict:
     """Run ``cell`` once; returns the result line's fields (without the
     device's platform check, which ``run.py`` makes first)."""
-    model, job = cell["model"], cell["job"]
+    model, job, family = cell["model"], cell["job"], cell["family"]
     watch = _watch()
     n_check = job["check_steps"]
 
@@ -118,6 +119,10 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *, t0: float,
                for d in devices)
     kind = devices[0].device_kind
     platform = devices[0].platform
+    if traced:
+        # the compiled text of the step the window ran, whose instruction
+        # names the trace's ops carry
+        hlo = sut.step.lower(state, batch).compile().as_text()
 
     # ---- free the program, then the reference ----------------------------
     del state, m, batch, inflight, window_losses
@@ -125,12 +130,13 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *, t0: float,
     tokens_per_step = sut.tokens_per_step
     del sut
     gc.collect()
-    ref = reference.first_steps(model, job, seed, n_steps=n_check)
+    ref = reference.first_steps(family, model, job, seed, n_steps=n_check)
     if fault == CONTROL:
-        prog = control(model, job, seed)
+        prog = control(cell, seed)
     read = compare.readings(prog, ref)
     correct, checks = compare.verdict(read, cell["limits"])
-    leaves = compare.leaf_lines(prog, ref, reference.leaf_shapes(model)[0])
+    leaves = compare.leaf_lines(prog, ref,
+                                reference.leaf_shapes(family, model)[0])
 
     out = {
         "correct": correct,
@@ -151,14 +157,18 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *, t0: float,
         return out
 
     # ---- per-layer metrics from the trace --------------------------------
-    summary = trace.reduce(trace.find_xplane(tmp.name),
-                           [d.id for d in devices])
+    ids = [d.id for d in devices]
+    profile = trace.load(trace.find_xplane(tmp.name))
+    summary = trace.reduce(profile, ids)
+    split = scopes.reduce(profile, ids, hlo)
+    del profile
     tmp.cleanup()
     ctx = {
-        "summary": summary, "steps": steps, "window_s": window_s,
+        "summary": summary, "scopes": split, "counters": counters,
+        "steps": steps, "window_s": window_s,
         "chips": mesh_devices, "peaks": peaks_of(kind),
         "train_flops_per_step": flops.train_flops_per_token(
-            model, job["seq"]) * tokens_per_step,
+            family, model, job["seq"]) * tokens_per_step,
         "model": model, "job": job,
     }
     metrics = {}
@@ -177,10 +187,11 @@ CONTROL = "control"
 PROGRAM_FAULTS = ("unchanged", "half_batch", "answer", "fused")
 
 
-def control(model, job, seed):
+def control(cell, seed):
     """The control: the reference's first steps in bfloat16, put in the
     program's place."""
-    return reference.first_steps(model, job, seed, n_steps=job["check_steps"],
+    return reference.first_steps(cell["family"], cell["model"], cell["job"],
+                                 seed, n_steps=cell["job"]["check_steps"],
                                  dtype=jax.numpy.bfloat16)
 
 
@@ -203,7 +214,8 @@ def first_steps(sut, n_check):
             counters = {k: m[k] for k in ("comm_bits_sparse",
                                           "comm_bits_dense",
                                           "collectives_per_step",
-                                          "density") if k in m}
+                                          "density", "ef_leaves_at_cap",
+                                          "ef_leaves_under_band") if k in m}
     prog = {"loss": [float(x) for x in losses],
             "update_norms": np.asarray(update_norms),
             "update_counts": np.asarray(update_counts),
@@ -224,9 +236,4 @@ def _change_norms(sut, state):
 
 def _reader(directory, name):
     """The reader of metric ``name``: ``metrics/<name>.py``."""
-    path = directory / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return spec.module(directory / f"{name}.py", "bench_metric_").read

@@ -4,9 +4,9 @@ Written from the equations, in straightforward ``jax.numpy``, and
 independent of the program under test: nothing here imports ``repro``.
 It covers every layer the timed step passes through:
 
-* the model's forward pass and loss (a pre-norm decoder: RMSNorm,
-  rotary attention and SwiGLU) and, through ``jax.grad``, its backward
-  pass;
+* the model's forward pass and loss, from the configuration's family
+  (``bench/families/<family>.py``: its ``param_shapes`` and ``loss``)
+  and, through ``jax.grad``, its backward pass;
 * Gaussian-k error feedback, paper Eq. (2): ``u = g + e``, the
   threshold of Algorithm 1 with its refinement band, the first
   ``k_cap`` coordinates over the threshold in index order, and the new
@@ -42,53 +42,39 @@ def layer_sig(model: dict, layer: int):
     return bp[layer % len(bp)], fp[layer % len(fp)]
 
 
-def head_dim(model: dict) -> int:
-    return model.get("head_dim") or model["d_model"] // model["num_heads"]
-
-
-def _block_shapes(model: dict, kind: str, ffn: str) -> dict:
-    D, H, KV = model["d_model"], model["num_heads"], model["num_kv_heads"]
-    hd = head_dim(model)
-    block = {"norm1": {"scale": (D,)}}
-    if kind == "attn":
-        block["core"] = {"wq": (D, H * hd), "wk": (D, KV * hd),
-                         "wv": (D, KV * hd), "wo": (H * hd, D)}
-    else:
-        raise ValueError(f"no reference for block kind {kind!r}")
-    if ffn == "mlp":
-        F = model["d_ff"]
-        block["norm2"] = {"scale": (D,)}
-        block["ffn"] = {"w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
-    elif ffn != "none":
-        raise ValueError(f"no reference for ffn kind {ffn!r}")
-    return block
-
-
-def param_shapes(model: dict) -> dict:
-    """Shape tree of the parameters: layer kinds of one pattern period
-    stacked over its repetitions, the remainder unstacked."""
+def pattern_shapes(model: dict, block_shapes) -> dict:
+    """The program's tree of parameter shapes for a cycled layer pattern:
+    layer kinds of one pattern period stacked over its repetitions, the
+    remainder unstacked.  ``block_shapes(model, kind, ffn)`` gives one
+    layer's shapes; a family gives its ``param_shapes`` through it."""
     D, V, L = model["d_model"], model["vocab_size"], model["num_layers"]
     P = period(model)
     reps, tail = divmod(L, P)
     stack = []
     for pos in range(P if reps else 0):
-        one = _block_shapes(model, *layer_sig(model, pos))
+        one = block_shapes(model, *layer_sig(model, pos))
         stack.append(jax.tree.map(lambda s: (reps,) + s, one,
                                   is_leaf=lambda s: isinstance(s, tuple)))
     return {"embed": (V, D), "final_norm": {"scale": (D,)},
             "lm_head": (D, V), "stack": stack,
-            "tail": [_block_shapes(model, *layer_sig(model, reps * P + i))
+            "tail": [block_shapes(model, *layer_sig(model, reps * P + i))
                      for i in range(tail)]}
 
 
-def leaf_shapes(model: dict) -> tuple:
-    """(names, shapes) of the leaves, in the order the program's tree
-    flattens; a name joins the path with '/', as ``stack/0/core/wq``."""
+def leaf_paths(shapes) -> tuple:
+    """(names, shapes) of the leaves of a tree of shapes, in the order
+    the tree flattens; a name joins the path with '/', as
+    ``stack/0/core/wq``."""
     flat, _ = jax.tree_util.tree_flatten_with_path(
-        param_shapes(model), is_leaf=lambda s: isinstance(s, tuple))
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
     return ["/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                      for k in path) for path, _ in flat], \
         [shape for _, shape in flat]
+
+
+def leaf_shapes(family, model: dict) -> tuple:
+    """``leaf_paths`` of the family's parameter shapes for ``model``."""
+    return leaf_paths(family.param_shapes(model))
 
 
 def _leaf_init(path, shape, key):
@@ -105,9 +91,9 @@ def _leaf_init(path, shape, key):
     return std * jax.random.normal(key, shape, jnp.float32)
 
 
-def init_params(model: dict, key) -> dict:
-    """The weights, from ``key`` alone.  Jit it (``model`` static)."""
-    shapes = param_shapes(model)
+def init_params(shapes, key) -> dict:
+    """The weights of a tree of shapes (tuples), from ``key`` alone.
+    Jit it with the shapes bound."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
     leaves = [_leaf_init(path, shape, jax.random.fold_in(key, i))
@@ -139,76 +125,6 @@ def lm_batch(step: int, *, global_batch: int, seq_len: int, vocab: int,
         out[:, i] = cur
     return {"tokens": out[:, :-1].astype(np.int32),
             "labels": out[:, 1:].astype(np.int32)}
-
-
-# ---------------------------------------------------------------------------
-# the model
-# ---------------------------------------------------------------------------
-
-
-def rmsnorm(scale, x):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + 1e-6)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def rope(x, theta: float):
-    """Rotary embedding over the whole head dim, halves rotated as pairs:
-    (x1, x2) -> (x1 cos - x2 sin, x1 sin + x2 cos).  x: (B, T, H, hd)."""
-    T, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
-                           -1).astype(x.dtype)
-
-
-def attention(p, x, model):
-    B, T, _ = x.shape
-    H, KV, hd = model["num_heads"], model["num_kv_heads"], head_dim(model)
-    q = rope((x @ p["wq"]).reshape(B, T, H, hd), model["rope_theta"])
-    k = rope((x @ p["wk"]).reshape(B, T, KV, hd), model["rope_theta"])
-    v = (x @ p["wv"]).reshape(B, T, KV, hd)
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    s = (jnp.einsum("bthd,bshd->bhts", q, k) / math.sqrt(hd)).astype(
-        jnp.float32)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    w = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), -1).astype(x.dtype)
-    o = jnp.einsum("bhts,bshd->bthd", w, v)
-    return o.reshape(B, T, H * hd) @ p["wo"]
-
-
-def block(p, h, model, ffn):
-    h = h + attention(p["core"], rmsnorm(p["norm1"]["scale"], h), model)
-    if ffn == "mlp":
-        y = rmsnorm(p["norm2"]["scale"], h)
-        f = p["ffn"]
-        h = h + (jax.nn.silu(y @ f["w_gate"]) * (y @ f["w_up"])) @ f["w_down"]
-    return h
-
-
-def loss(params, tokens, labels, model):
-    """Mean next-token cross-entropy over every position of the batch."""
-    h = params["embed"][tokens]
-    P = period(model)
-
-    @jax.checkpoint
-    def rep(h, p_rep):
-        for pos in range(P):
-            h = block(p_rep[pos], h, model, layer_sig(model, pos)[1])
-        return h, None
-
-    if params["stack"]:
-        h, _ = jax.lax.scan(rep, h, params["stack"])
-    base = (model["num_layers"] // P) * P
-    for i, p in enumerate(params["tail"]):
-        h = block(p, h, model, layer_sig(model, base + i)[1])
-    logits = (rmsnorm(params["final_norm"]["scale"], h)
-              @ params["lm_head"]).astype(jnp.float32)
-    picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jnp.mean(jax.nn.logsumexp(logits, -1) - picked)
 
 
 # ---------------------------------------------------------------------------
@@ -257,10 +173,11 @@ def leaf_counts(tree) -> np.ndarray:
     return np.asarray([int(_count(x)) for x in jax.tree.leaves(tree)])
 
 
-def first_steps(model: dict, job: dict, seed: int, *, n_steps: int = 3,
-                dtype=jnp.float32) -> dict:
-    """Run the reference (or, with ``dtype=bfloat16``, the control)
-    through the first ``n_steps`` steps of the job from ``seed``.
+def first_steps(family, model: dict, job: dict, seed: int, *,
+                n_steps: int = 3, dtype=jnp.float32) -> dict:
+    """Run the reference (or, with ``dtype=bfloat16``, the control) of
+    ``family``'s ``model`` through the first ``n_steps`` steps of the job
+    from ``seed``.
 
     Returns ``loss`` (one per step), ``update_norms`` and
     ``update_counts`` (per leaf: the norm and the nonzero count of the
@@ -275,8 +192,8 @@ def first_steps(model: dict, job: dict, seed: int, *, n_steps: int = 3,
     lr, mu, ratio = job["lr"], job["momentum"], job["ratio"]
     prec = "highest" if dtype == jnp.float32 else "default"
     cast = partial(jax.tree.map, lambda x: x.astype(dtype))
-    params = cast(jax.jit(init_params, static_argnums=0)(
-        _frozen(model), jax.random.PRNGKey(seed)))
+    make = jax.jit(partial(init_params, family.param_shapes(model)))
+    params = cast(make(jax.random.PRNGKey(seed)))
     shapes = [x.shape for x in jax.tree.leaves(params)]
     budgets = [gaussiank_budget(int(np.prod(s)), ratio) for s in shapes]
     treedef = jax.tree.structure(params)
@@ -284,8 +201,8 @@ def first_steps(model: dict, job: dict, seed: int, *, n_steps: int = 3,
     @jax.jit
     def grad_fn(params, tokens, labels):
         with jax.default_matmul_precision(prec):
-            return jax.value_and_grad(loss)(params, tokens, labels,
-                                            _frozen(model))
+            return jax.value_and_grad(family.loss)(params, tokens, labels,
+                                                   model)
 
     @partial(jax.jit, static_argnums=(2, 3))
     def ef_leaf(g, e, k, k_cap):
@@ -336,8 +253,7 @@ def first_steps(model: dict, job: dict, seed: int, *, n_steps: int = 3,
                 new, jax.tree.leaves(params))
         params = jax.tree.unflatten(treedef, new)
         del new
-    p0 = cast(jax.jit(init_params, static_argnums=0)(
-        _frozen(model), jax.random.PRNGKey(seed)))
+    p0 = cast(make(jax.random.PRNGKey(seed)))
     out["change_norms"] = _change_norms(jax.tree.leaves(params),
                                         jax.tree.leaves(p0))
     return out
@@ -362,9 +278,3 @@ def _norm(x):
 def _count(x):
     return jnp.count_nonzero(x)
 
-
-class _frozen(dict):
-    """A dict that jit can take as a static argument."""
-
-    def __hash__(self):
-        return hash(repr(sorted(self.items())))

@@ -1,11 +1,13 @@
 """The system under test: the program's compressed training step, built
 the way ``repro/launch/train.py`` builds it, with the weights made by
-the benchmark (``reference.init_params``) from the seed.
+the benchmark (``reference.init_params``) from the seed, over the
+program's own tree of parameters.
 
 This module is the one place that imports the program.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -15,19 +17,21 @@ from jax.sharding import PartitionSpec as P
 
 from bench import reference
 
-# ModelConfig fields a configuration file may set
-_MODEL_KEYS = ("name", "arch_type", "num_layers", "d_model", "num_heads",
-               "num_kv_heads", "d_ff", "vocab_size", "head_dim",
-               "block_pattern", "ffn_pattern", "rope_theta", "use_bias",
-               "param_dtype", "activation_dtype", "source")
-
-
 def model_config(model: dict):
+    """The program's ``ModelConfig`` of a configuration's ``model``: every
+    key it sets is passed.  Raises KeyError for a key that is not a
+    field of ``ModelConfig``."""
     from repro.models.config import ModelConfig
 
-    kw = {k: model[k] for k in _MODEL_KEYS if k in model}
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(model) - fields)
+    if unknown:
+        raise KeyError(f"{model.get('name')!r}: ModelConfig has no field "
+                       f"{unknown}")
+    kw = dict(model)
     for k in ("block_pattern", "ffn_pattern"):
-        kw[k] = tuple(kw[k])
+        if k in kw:
+            kw[k] = tuple(kw[k])
     return ModelConfig(**kw).validate()
 
 
@@ -49,6 +53,7 @@ class System:
         from repro.dist.layout import build_layout
         from repro.dist.sharding import train_state_specs
         from repro.launch.mesh import make_mesh
+        from repro.models import init_params as model_init
         from repro.models import loss_fn as model_loss
         from repro.optim import constant, sgd_momentum
         from repro.train import init_train_state, make_train_step
@@ -63,15 +68,16 @@ class System:
             compressor=job["compressor"], ratio=job["ratio"],
             strategy=job["strategy"],
             backend="fused" if fault == "fused" else job["backend"])
-        frozen = reference._frozen(model)
         key = jax.random.PRNGKey(seed)
-        shapes = jax.eval_shape(partial(reference.init_params, frozen), key)
+        shapes = jax.tree.map(lambda s: tuple(s.shape), jax.eval_shape(
+            partial(model_init, cfg), key))
+        init = partial(reference.init_params, shapes)
         # 1. the bucket layout, from the parameters' shapes
-        self.layout = build_layout(shapes, M, comp)
+        self.layout = build_layout(jax.eval_shape(init, key), M, comp)
 
         # 2-3. the state, made on the device with the step's shardings
         def make_state(key):
-            params = reference.init_params(frozen, key)
+            params = init(key)
             return init_train_state(params, opt, workers=W, model_size=M,
                                     compression=comp, layout=self.layout)
 
@@ -80,8 +86,7 @@ class System:
         self._key = key
         self._make_state = jax.jit(make_state, out_shardings=shard)
         self._make_params = jax.jit(
-            partial(reference.init_params, frozen),
-            out_shardings=NamedSharding(self.mesh, P()))
+            init, out_shardings=NamedSharding(self.mesh, P()))
 
         # 4. the step
         loss_fn = None

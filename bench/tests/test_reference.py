@@ -1,6 +1,7 @@
 """The reference against the program at a small size on the CPU, and its
 weights in the program's layout at the cells' real sizes (shapes only)."""
 import json
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,8 @@ import numpy as np
 import pytest
 
 from bench import reference, spec, system
-from bench.tests import tiny
+from bench.families import decoder
+from bench.tests import moe_family, tiny
 
 
 @pytest.mark.parametrize("name", ["stablelm-2-1.6b-L3"])
@@ -20,7 +22,8 @@ def test_weights_in_program_layout(name):
     model = conf["model"]
     key = jax.random.PRNGKey(0)
     ours = jax.eval_shape(
-        lambda k: reference.init_params(reference._frozen(model), k), key)
+        lambda k: reference.init_params(
+            spec.family(conf["family"]).param_shapes(model), k), key)
     theirs = jax.eval_shape(
         lambda k: init_params(system.model_config(model), k), key)
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
@@ -31,20 +34,30 @@ def test_weights_in_program_layout(name):
 
 
 def test_loss_and_grad_match_program():
-    model = tiny.DENSE
+    _loss_and_grad_match_program(decoder, tiny.DENSE)
+
+
+def test_moe_family_loss_and_grad_match_program():
+    """The test-only MoE family against the program's MoE FFN, with its
+    shared expert and load-balance term."""
+    _loss_and_grad_match_program(moe_family, tiny.MOE)
+
+
+def _loss_and_grad_match_program(family, model):
     from repro.data import batch_for
     from repro.models import loss_fn
 
     cfg = system.model_config(model)
-    params = jax.jit(reference.init_params, static_argnums=0)(
-        reference._frozen(model), jax.random.PRNGKey(3))
+    params = jax.jit(partial(reference.init_params,
+                             family.param_shapes(model)))(
+        jax.random.PRNGKey(3))
     batch = batch_for(cfg, 0, global_batch=2, seq_len=32, seed=5)
     ref_batch = reference.lm_batch(0, global_batch=2, seq_len=32,
                                    vocab=model["vocab_size"], seed=5)
     np.testing.assert_array_equal(batch["tokens"], ref_batch["tokens"])
     np.testing.assert_array_equal(batch["labels"], ref_batch["labels"])
     with jax.default_matmul_precision("highest"):
-        lr, gr = jax.value_and_grad(reference.loss)(
+        lr, gr = jax.value_and_grad(family.loss)(
             params, ref_batch["tokens"], ref_batch["labels"], model)
         (lp, _), gp = jax.value_and_grad(
             lambda p: loss_fn(p, cfg, batch, remat=False), has_aux=True)(

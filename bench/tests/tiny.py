@@ -8,6 +8,13 @@ DENSE = {"name": "tiny-dense", "arch_type": "dense", "num_layers": 2,
          "d_model": 64, "num_heads": 4, "num_kv_heads": 4, "d_ff": 128,
          "vocab_size": 256, "rope_theta": 10000.0,
          "block_pattern": ["attn"], "ffn_pattern": ["mlp"]}
+# the decoder with a routed mixture of experts in every second layer
+# (``bench/tests/moe_family.py``): 4 experts, 2 a token, one shared, room
+# for every token (no drops)
+MOE = dict(DENSE, name="tiny-moe", arch_type="moe", num_layers=3,
+           ffn_pattern=["mlp", "moe"], num_experts=4, experts_per_token=2,
+           num_shared_experts=1, moe_d_ff=64, capacity_factor=2.0,
+           router_aux_coef=0.01)
 STANDS_FOR = {"stablelm_efjnp_1chip": DENSE}
 
 
