@@ -5,6 +5,7 @@ from __future__ import annotations
 from repro.configs import (
     command_r_35b,
     deepseek_moe_16b,
+    deepseek_v2_lite,
     gemma3_4b,
     jamba_15_large,
     llama32_1b,
@@ -22,7 +23,7 @@ ARCHS = {
     for c in (
         phi35_moe_42b, llama32_1b, stablelm_16b, gemma3_4b, jamba_15_large,
         musicgen_medium, llava_next_34b, command_r_35b, xlstm_125m,
-        deepseek_moe_16b,
+        deepseek_moe_16b, deepseek_v2_lite,
     )
 }
 

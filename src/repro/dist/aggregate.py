@@ -583,6 +583,16 @@ def aggregate_dense(grads, data_axes):
     return jax.tree.map(lambda g: jax.lax.pmean(g, axes), grads)
 
 
+def decode_width(d_row: int) -> int:
+    """Width to decode a bucket's mean into: ``d_row`` rounded up to the
+    TPU's tile of a 1-D f32 array, 1024 elements (8 x 128).  The chip's
+    compiler decodes into a 1-D buffer; at a width off that tile, viewing
+    it as the ``(model_size, d_row)`` bucket is a relayout, which it runs
+    as a copy loop holding two more bucket-sized buffers.  The columns
+    past ``d_row`` stay zero, and ``unpack_tree`` reads segments only."""
+    return -(-d_row // 1024) * 1024
+
+
 @jax.named_scope("wire")
 def _gather_mean(values, indices, axis, n: int, d_row: int, dtype):
     """All-gather fixed-capacity pairs over ``axis`` and decode-average.
@@ -1173,8 +1183,8 @@ def _aggregate_bucketed(grads, resid, layout: BucketLayout,
         mean = dense_sum / world
         new_E = new_E + merge_drop.astype(new_E.dtype)
     else:
-        mean = _gather_mean(values, indices, inner_axes, n_inner, D,
-                            jnp.float32)
+        mean = _gather_mean(values, indices, inner_axes, n_inner,
+                            D if hier else decode_width(D), jnp.float32)
 
     if hier:
         # second level: compress the pod-mean bucket against resid2 and
@@ -1192,7 +1202,8 @@ def _aggregate_bucketed(grads, resid, layout: BucketLayout,
             mean = dense2 / n_pods
             new_R2 = new_R2 + drop2.astype(new_R2.dtype)
         else:
-            mean = _gather_mean(v2, i2, outer_axis, n_pods, D, jnp.float32)
+            mean = _gather_mean(v2, i2, outer_axis, n_pods,
+                                decode_width(D), jnp.float32)
         nnz_local += codec.nnz(i2).astype(jnp.float32)
     elif mc > 0.0:
         new_R2 = new_V

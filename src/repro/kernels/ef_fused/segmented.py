@@ -30,30 +30,9 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import AxisType
-from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ef_fused.ops import fused_compress_ef, fused_pass_a
-
-
-def _manual_over_auto_axes(fn, *args):
-    """Call ``fn`` with every mesh axis that is still auto made manual.
-
-    GSPMD cannot partition a Mosaic kernel, and lowering refuses one in a
-    region that is manual over some mesh axes only — the train step's
-    ``shard_map`` is manual over the data axes and automatic over
-    ``model``.  So the row block runs in a nested ``shard_map`` over the
-    remaining axes with replicated operands: every device computes every
-    row, the values GSPMD would have replicated.  Outside a mesh, or in a
-    region that is already fully manual, ``fn`` is called as is.
-    """
-    mesh = jax.sharding.get_abstract_mesh()
-    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
-            if t != AxisType.Manual}
-    if mesh.empty or not auto:
-        return fn(*args)
-    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                         axis_names=auto, check_vma=False)(*args)
+from repro.spmd import manual_over_auto_axes
 
 
 def rows_pass_a(g_rows: jax.Array, e_rows: jax.Array, name: str,
@@ -67,7 +46,7 @@ def rows_pass_a(g_rows: jax.Array, e_rows: jax.Array, name: str,
         return [fused_pass_a(g_rows[r], e_rows[r], name, backend=backend)
                 for r in range(g_rows.shape[0])]
 
-    return _manual_over_auto_axes(rows, g_rows, e_rows)
+    return manual_over_auto_axes(rows, g_rows, e_rows)
 
 
 def rows_compress_ef(g_rows: jax.Array, e_rows: jax.Array, name: str, k, *,
@@ -90,7 +69,7 @@ def rows_compress_ef(g_rows: jax.Array, e_rows: jax.Array, name: str, k, *,
                 for r in range(g_rows.shape[0])]
         return tuple(jnp.stack([o[i] for o in outs]) for i in range(3))
 
-    return _manual_over_auto_axes(rows, g_rows, e_rows, k, row_stats)
+    return manual_over_auto_axes(rows, g_rows, e_rows, k, row_stats)
 
 
 def segmented_pass_a(g2d: jax.Array, e2d: jax.Array,

@@ -373,6 +373,12 @@ def train(argv=None) -> list:
             if outcome["ef_leaves_at_cap"] is not None:
                 comm += (f" at_cap={outcome['ef_leaves_at_cap']:g}"
                          f" under_band={outcome['ef_leaves_under_band']:g}")
+            # the expert layers' routing (DESIGN.md §17)
+            if "moe_rows_held" in m:
+                comm += (f" moe_rows_held={float(m['moe_rows_held']):g}"
+                         f" moe_load_max={float(m['moe_load_max']):.4g}"
+                         f" moe_rows_dropped="
+                         f"{float(m['moe_rows_dropped']):g}")
             if decision is not None:
                 # record the auto decision alongside the step metrics
                 comm += (f" tuner={decision.strategy}"

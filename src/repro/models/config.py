@@ -21,11 +21,16 @@ class ModelConfig:
     # layer pattern, cycled over layers. entries:
     #   attn        full-causal GQA attention
     #   swa         sliding-window GQA attention
+    #   mla         multi-head latent attention (DeepSeek-V2), training only
     #   mamba       selective-SSM (Mamba) block
     #   slstm/mlstm xLSTM blocks
     block_pattern: Tuple[str, ...] = ("attn",)
     # ffn per layer, cycled:  mlp | moe | none
     ffn_pattern: Tuple[str, ...] = ("mlp",)
+    # leading layers of ffn "mlp" at d_ff that run, unrolled, before the
+    # patterned layers (DeepSeek's first_k_dense_replace); num_layers
+    # counts them
+    first_dense_layers: int = 0
 
     # attention
     rope_theta: float = 10_000.0
@@ -33,13 +38,44 @@ class ModelConfig:
     parallel_block: bool = False       # command-r style attn ∥ ffn
     use_bias: bool = False
 
+    # latent attention ("mla"): K and V from a compressed latent of
+    # kv_latent_dim (DeepSeek's kv_lora_rank) with its own RMSNorm; each
+    # head scores over qk_nope_head_dim + qk_rope_head_dim dims, the
+    # rotary key one head shared by all heads
+    kv_latent_dim: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling; factor 1 is plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
     # MoE
     num_experts: int = 0
     experts_per_token: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0                  # per-expert hidden dim (0 -> d_ff)
-    capacity_factor: float = 1.25
+    # per-expert cap on the sorted rows; None routes every token (dropless)
+    capacity_factor: Optional[float] = 1.25
     router_aux_coef: float = 0.01
+    # "switch": coef * E * sum_e mean(p_e) * top-1 share of e, over the
+    # batch; "seq": DeepSeek's sequence-wise loss, coef * mean over
+    # sequences of sum_e f_e * P_e, f_e = E / (K T) * assignments to e
+    router_aux: str = "switch"
+    norm_topk_prob: bool = True        # renormalize the top-k gates
+    routed_scaling_factor: float = 1.0
+    # the layer routes over all num_experts and holds experts
+    # [expert_offset, expert_offset + experts_held); 0 holds all
+    experts_held: int = 0
+    expert_offset: int = 0
+    # dtype of the operands of the expert products (float32 accumulation):
+    # float32 for the presets whose tests hold them to a float32
+    # reference; deepseek-v2-lite sets bfloat16, as its benchmark cell runs
+    expert_operand_dtype: str = "float32"
 
     # SSM (Mamba)
     ssm_state_dim: int = 16
@@ -97,11 +133,30 @@ class ModelConfig:
     def expert_d_ff(self) -> int:
         return self.moe_d_ff if self.moe_d_ff else self.d_ff
 
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held if self.experts_held else self.num_experts
+
+    @property
+    def mla_q_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
     def validate(self) -> "ModelConfig":
         assert self.d_model % self.num_heads == 0 or self.head_dim, self.name
         assert self.num_heads % self.num_kv_heads == 0, self.name
         if "moe" in self.ffn_pattern:
             assert self.num_experts > 0 and self.experts_per_token > 0, self.name
+            assert 0 <= self.expert_offset, self.name
+            assert (self.expert_offset + self.held_experts
+                    <= self.num_experts), self.name
+            assert self.router_aux in ("switch", "seq"), self.name
+        if "mla" in self.block_pattern:
+            assert min(self.kv_latent_dim, self.qk_nope_head_dim,
+                       self.qk_rope_head_dim, self.v_head_dim) > 0, self.name
+            assert self.qk_rope_head_dim % 2 == 0, self.name
+            assert self.num_kv_heads == self.num_heads, self.name
+        assert self.rope_scaling_factor >= 1.0, self.name
+        assert 0 <= self.first_dense_layers <= self.num_layers, self.name
         assert self.frontend in ("tokens", "embeds"), self.name
         return self
 
@@ -131,7 +186,16 @@ class ModelConfig:
             if self.num_shared_experts else 0,
             moe_d_ff=min(self.moe_d_ff, 256) if self.moe_d_ff else 0,
             sliding_window=128,
+            first_dense_layers=min(self.first_dense_layers, 1),
+            kv_latent_dim=min(self.kv_latent_dim, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
         )
+        if self.experts_held:
+            small["experts_held"] = min(self.experts_held,
+                                        small["num_experts"])
+            small["expert_offset"] = 0
         hd2 = small["d_model"] // small["num_heads"]
         if small["head_dim"] is not None:
             small["head_dim"] = hd2

@@ -113,11 +113,14 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig):
 _SDPA_CHUNK = 1024
 
 
-def _sdpa_chunked(q, k, v, cfg: ModelConfig, window: int, chunk: int):
+def _sdpa_chunked(q, k, v, cfg: ModelConfig, window: int, chunk: int,
+                  remat: bool = False):
     """Query-blocked causal attention: lax.scan over query chunks so only a
     (B, H, chunk, S) logit block is ever live — O(T·chunk) memory instead of
     O(T²).  This is the XLA-level analogue of flash attention's outer loop;
-    it is what makes ``prefill_32k`` lowerable at sane HBM footprints."""
+    it is what makes ``prefill_32k`` lowerable at sane HBM footprints.
+    ``remat`` recomputes each block's scores in the backward pass instead
+    of keeping every block's for it."""
     B, T, H, hd = q.shape
     S = k.shape[1]
     assert T % chunk == 0, (T, chunk)
@@ -134,9 +137,11 @@ def _sdpa_chunked(q, k, v, cfg: ModelConfig, window: int, chunk: int):
             mask &= kpos[None, :] > qpos[:, None] - window
         return None, _sdpa(qi, k, v, mask, cfg)
 
+    if remat:
+        body = jax.checkpoint(body)
     starts = jnp.arange(nch) * chunk
     _, out = jax.lax.scan(body, None, (qc, starts))
-    return jnp.moveaxis(out, 0, 1).reshape(B, T, H, hd)
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, H, v.shape[-1])
 
 
 def causal_mask(T: int, S: int, window: int = 0):

@@ -1,10 +1,12 @@
 """Unified decoder model covering all assigned architecture families.
 
-A model is a cycled ``block_pattern`` (attn / swa / mamba / slstm / mlstm)
-crossed with a cycled ``ffn_pattern`` (mlp / moe / none).  Layers are grouped
-into ``reps`` repetitions of the pattern period and executed under
-``jax.lax.scan`` with period-position-stacked parameters (compile time stays
-O(period), not O(num_layers)); the ``num_layers % period`` tail runs unrolled.
+A model is a cycled ``block_pattern`` (attn / swa / mla / mamba / slstm /
+mlstm) crossed with a cycled ``ffn_pattern`` (mlp / moe / none), after
+``first_dense_layers`` leading layers of ffn ``mlp`` (``params["lead"]``,
+unrolled).  The patterned layers are grouped into ``reps`` repetitions of
+the pattern period and executed under ``jax.lax.scan`` with
+period-position-stacked parameters (compile time stays O(period), not
+O(num_layers)); the remainder's tail runs unrolled.
 
 Three entry points per model:
   ``loss_fn``      training forward + cross-entropy (+ MoE aux loss)
@@ -18,6 +20,7 @@ is what makes gemma3-style 5:1 local:global viable at 500k context.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -26,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from repro.models import layers as L
+from repro.models import mla as A
 from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import xlstm as X
@@ -41,6 +45,8 @@ def _init_block(key, cfg: ModelConfig, kind: str, ffn: str, dtype):
     p: Dict[str, Any] = {"norm1": L.init_rmsnorm(cfg.d_model, dtype)}
     if kind in ("attn", "swa"):
         p["core"] = L.init_attention(kb, cfg, dtype)
+    elif kind == "mla":
+        p["core"] = A.init_mla(kb, cfg, dtype)
     elif kind == "mamba":
         p["core"] = S.init_mamba(kb, cfg, dtype)
     elif kind == "mlstm":
@@ -58,11 +64,17 @@ def _init_block(key, cfg: ModelConfig, kind: str, ffn: str, dtype):
     return p
 
 
+def _lead_sig(cfg: ModelConfig, i: int):
+    """(block kind, ffn kind) of leading dense layer ``i``."""
+    return cfg.block_kind(i), "mlp"
+
+
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     cfg.validate()
     dtype = jnp.dtype(cfg.param_dtype)
     period = cfg.pattern_period
-    reps, tail = divmod(cfg.num_layers, period)
+    lead = cfg.first_dense_layers
+    reps, tail = divmod(cfg.num_layers - lead, period)
     k_embed, k_head, k_layers = jax.random.split(key, 3)
 
     params: Dict[str, Any] = {
@@ -72,6 +84,10 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         "lm_head": L._dense_init(k_head, (cfg.d_model, cfg.vocab_size), dtype),
     }
     lkeys = jax.random.split(k_layers, cfg.num_layers)
+    if lead:
+        params["lead"] = [_init_block(lkeys[i], cfg, *_lead_sig(cfg, i),
+                                      dtype) for i in range(lead)]
+    lkeys = lkeys[lead:]
     stack = []
     for pos in range(period if reps else 0):
         kind, ffn = cfg.layer_sig(pos)
@@ -103,6 +119,8 @@ def _apply_core(p, h, cfg: ModelConfig, kind: str):
         window = cfg.sliding_window if kind == "swa" else 0
         out, (k, v) = L.attention(p, h, cfg, window=window)
         return out, ("kv", k, v)
+    if kind == "mla":
+        return A.mla(p, h, cfg), None
     if kind == "mamba":
         out, ssm_state, conv_tail = S.mamba_forward(p, h, cfg)
         return out, ("mamba", ssm_state, conv_tail)
@@ -116,23 +134,48 @@ def _apply_core(p, h, cfg: ModelConfig, kind: str):
 
 
 def _apply_block(p, h, cfg: ModelConfig, kind: str, ffn: str):
-    """Returns (h, aux_loss, cache_contrib)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (h, side, cache_contrib): ``side`` holds the layer's
+    ``aux`` loss and, for an expert layer, its counters
+    (``moe.moe_layer``)."""
+    side = {"aux": jnp.zeros((), jnp.float32)}
     normed = L.rmsnorm(p["norm1"], h)
     core_out, cache = _apply_core(p["core"], normed, cfg, kind)
     if cfg.parallel_block and ffn != "none":
         f_out = L.mlp(p["ffn"], normed) if ffn == "mlp" else None
         if ffn == "moe":
-            f_out, aux = M.moe_ffn(p["ffn"], normed, cfg)
+            f_out, side["aux"], stats = M.moe_layer(p["ffn"], normed, cfg)
+            side.update(stats)
         h = h + core_out + f_out
-        return h, aux, cache
+        return h, side, cache
     h = h + core_out
     if ffn == "mlp":
         h = h + L.mlp(p["ffn"], L.rmsnorm(p["norm2"], h))
     elif ffn == "moe":
-        f_out, aux = M.moe_ffn(p["ffn"], L.rmsnorm(p["norm2"], h), cfg)
+        f_out, side["aux"], stats = M.moe_layer(
+            p["ffn"], L.rmsnorm(p["norm2"], h), cfg)
+        side.update(stats)
         h = h + f_out
-    return h, aux, cache
+    return h, side, cache
+
+
+def _merge(a: dict, b: dict) -> dict:
+    """Two layers' side outputs as one: sums, and the larger
+    ``moe_load_max``."""
+    out = dict(a)
+    for k, v in b.items():
+        if k not in out:
+            out[k] = v
+        elif k == "moe_load_max":
+            out[k] = jnp.maximum(out[k], v)
+        else:
+            out[k] = out[k] + v
+    return out
+
+
+def _over_reps(side: dict) -> dict:
+    """The scan's stacked side outputs reduced over the repetitions."""
+    return {k: jnp.max(v) if k == "moe_load_max" else jnp.sum(v)
+            for k, v in side.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -140,30 +183,42 @@ def _apply_block(p, h, cfg: ModelConfig, kind: str, ffn: str):
 # ---------------------------------------------------------------------------
 
 
-def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
-            remat: bool = True, constrain=None):
-    """Full-sequence forward -> (logits, aux_loss)."""
+def _forward(params, cfg: ModelConfig, tokens=None, embeds=None,
+             remat: bool = True, constrain=None):
+    """Full-sequence forward -> (logits, side), ``side`` the layers'
+    merged side outputs (``_apply_block``)."""
     adt = jnp.dtype(cfg.activation_dtype)
     if embeds is not None:
         h = embeds.astype(adt)
     else:
         h = params["embed"][tokens].astype(adt)
     period = cfg.pattern_period
-    reps = cfg.num_layers // period
+    reps = (cfg.num_layers - cfg.first_dense_layers) // period
+    side = {"aux": jnp.zeros((), jnp.float32)}
+
+    for i, p in enumerate(params.get("lead", [])):
+        if constrain is not None:
+            p = constrain(p)
+        block = partial(_apply_block, cfg=cfg, kind=_lead_sig(cfg, i)[0],
+                        ffn="mlp")
+        if remat:
+            block = jax.checkpoint(block)
+        h, s, _ = block(p, h)
+        side = _merge(side, s)
 
     def period_body(h, p_rep):
         if constrain is not None:
             p_rep = constrain(p_rep)
-        aux = jnp.zeros((), jnp.float32)
+        side = {"aux": jnp.zeros((), jnp.float32)}
         for pos in range(period):
             kind, ffn = cfg.layer_sig(pos)
-            h, a, _ = _apply_block(p_rep[pos], h, cfg, kind, ffn)
-            aux = aux + a
+            h, s, _ = _apply_block(p_rep[pos], h, cfg, kind, ffn)
+            side = _merge(side, s)
         if cfg.shard_activations:
             # §Perf knob: store the layer-boundary carry model-sharded
             h = jax.lax.with_sharding_constraint(
                 h, PartitionSpec(None, None, "model"))
-        return h, aux
+        return h, side
 
     if reps:
         body = jax.checkpoint(period_body) if remat else period_body
@@ -171,29 +226,37 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
         def scan_body(h, p_rep):
             return body(h, p_rep)
 
-        h, auxs = jax.lax.scan(scan_body, h, params["stack"])
-        aux = jnp.sum(auxs)
-    else:
-        aux = jnp.zeros((), jnp.float32)
+        h, sides = jax.lax.scan(scan_body, h, params["stack"])
+        side = _merge(side, _over_reps(sides))
     base = reps * period
     for i, p in enumerate(params["tail"]):
         if constrain is not None:
             p = constrain(p)
         kind, ffn = cfg.layer_sig(base + i)
-        h, a, _ = _apply_block(p, h, cfg, kind, ffn)
-        aux = aux + a
+        h, s, _ = _apply_block(p, h, cfg, kind, ffn)
+        side = _merge(side, s)
     h = L.rmsnorm(params["final_norm"], h)
     logits = h @ params["lm_head"].astype(adt)
-    return logits, aux
+    return logits, side
+
+
+def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
+            remat: bool = True, constrain=None):
+    """Full-sequence forward -> (logits, aux_loss)."""
+    logits, side = _forward(params, cfg, tokens, embeds, remat, constrain)
+    return logits, side["aux"]
 
 
 def loss_fn(params, cfg: ModelConfig, batch, remat: bool = True,
             constrain=None):
     """batch: {"tokens": (B,S)} or {"embeds": (B,S,D)}, plus "labels": (B,S).
-    Returns (loss, metrics)."""
-    logits, aux = forward(params, cfg, tokens=batch.get("tokens"),
-                          embeds=batch.get("embeds"), remat=remat,
-                          constrain=constrain)
+    Returns (loss, metrics): ``ce``, ``aux``, ``loss`` and, where the model
+    has expert layers, their counters (``moe.moe_layer``) over all of
+    them."""
+    logits, side = _forward(params, cfg, tokens=batch.get("tokens"),
+                            embeds=batch.get("embeds"), remat=remat,
+                            constrain=constrain)
+    aux = side.pop("aux")
     labels = batch["labels"]
     # CE via one-hot-einsum + logsumexp: take_along_axis would gather over
     # the vocab dim, which is model-sharded — the one-hot product reduces
@@ -206,12 +269,21 @@ def loss_fn(params, cfg: ModelConfig, batch, remat: bool = True,
     mask = batch.get("loss_mask", jnp.ones_like(ll))
     ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     loss = ce + aux
-    return loss, {"ce": ce, "aux": aux, "loss": loss}
+    return loss, {"ce": ce, "aux": aux, "loss": loss, **side}
 
 
 # ---------------------------------------------------------------------------
 # serving: cache init / prefill / decode
 # ---------------------------------------------------------------------------
+
+
+def _check_serving(cfg: ModelConfig):
+    """The serving path keeps no latent cache and runs no leading dense
+    layers."""
+    if "mla" in cfg.block_pattern or cfg.first_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: serving (init_cache / prefill / decode_step) does "
+            "not take latent attention or leading dense layers")
 
 
 def _cache_len(cfg: ModelConfig, kind: str, s_max: int) -> int:
@@ -238,6 +310,7 @@ def _init_layer_cache(cfg: ModelConfig, kind: str, B: int, s_max: int, dtype):
 
 
 def init_cache(cfg: ModelConfig, B: int, s_max: int, dtype=None):
+    _check_serving(cfg)
     dtype = dtype or jnp.dtype(cfg.activation_dtype)
     period = cfg.pattern_period
     reps, tail = divmod(cfg.num_layers, period)
@@ -367,6 +440,7 @@ def decode_step(params, cfg: ModelConfig, cache, pos, tokens=None,
                 embeds=None, constrain=None):
     """One decode step.  tokens: (B,1) ints or embeds: (B,1,D).
     pos: scalar int32 (current absolute position).  Returns (logits, cache)."""
+    _check_serving(cfg)
     adt = jnp.dtype(cfg.activation_dtype)
     if embeds is not None:
         h = embeds.astype(adt)

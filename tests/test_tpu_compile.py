@@ -293,3 +293,108 @@ def test_tiny_step_layers_on_v5e(tiny_step_v5e, check):
                   for _, i in instrs if i.op == "while"}
         in_loop = [comp_of[name] in bodies for name in found]
         assert any(in_loop) and not all(in_loop), found
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's grouped products at the benchmark's widths
+# ---------------------------------------------------------------------------
+
+
+def test_expert_layer_compiles_at_real_width(one_chip):
+    """One expert layer of the DeepSeek-V2-Lite cell (hidden 2048, expert
+    width 1408, 8 of 64 experts held, 6 a token, 2 shared, bf16 operands)
+    over 4096 tokens, forward and backward, compiled for the described
+    chip: megablox's grouped products lower as Mosaic kernels, three in
+    the forward pass and three pairs in the backward."""
+    from repro.kernels.ef_fused import tuning
+    from repro.models import moe
+    from repro.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        name="dsv2-moe", arch_type="moe", num_layers=1, d_model=2048,
+        num_heads=16, num_kv_heads=16, d_ff=10944, vocab_size=12800,
+        ffn_pattern=("moe",), num_experts=64, experts_per_token=6,
+        num_shared_experts=2, moe_d_ff=1408, capacity_factor=None,
+        router_aux="seq", router_aux_coef=0.001, norm_topk_prob=False,
+        experts_held=8, expert_operand_dtype="bfloat16").validate()
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: moe.init_moe(k, cfg, jnp.float32),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.float32,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        out, aux, stats = moe.moe_layer(p, x, cfg)
+        return jnp.sum(out) + aux + stats["moe_rows_dropped"]
+
+    with tuning.use_backend("mosaic"):
+        fwd = _kernels(loss, params, x)
+        both = _kernels(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert fwd == 3
+    assert both == 9
+
+
+def _gathers_of(text: str, dims) -> int:
+    """All-gathers in compiled ``text`` whose result ends in one of
+    ``dims`` (as HLO writes them, e.g. ``4,256,384``: any stacking dims
+    may lead)."""
+    ends = [re.compile(rf"[\[,]{d}\]") for d in dims]
+    return sum(1 for line in text.splitlines()
+               for m in [re.search(r"= (\S+) all-gather\(", line)] if m
+               and any(e.search(m.group(1)) for e in ends))
+
+
+@pytest.mark.parametrize("split", [True, False])
+def test_expert_layer_keeps_model_split_on_v5e(one_chip, split):
+    """The train step of a small expert model on a 2 x 2 (data x model)
+    mesh of the described chips: its grouped products lower as Mosaic
+    kernels, and no expert stack is gathered over ``model`` (each device
+    multiplies its share of the expert width, as ``dist/sharding`` splits
+    it).  With the split taken away (the products replicated over
+    ``model``), the gathers appear: the check can see them."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.compression import CompressionConfig
+    from repro.dist.sharding import train_state_specs
+    from repro.kernels.ef_fused import tuning
+    from repro.models import init_params, moe
+    from repro.models.config import ModelConfig
+    from repro.optim import constant, sgd_momentum
+    from repro.train import init_train_state, make_train_step
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = ModelConfig(
+        name="moe-split", arch_type="moe", num_layers=1, d_model=256,
+        num_heads=2, num_kv_heads=2, d_ff=512, vocab_size=256,
+        ffn_pattern=("moe",), num_experts=8, experts_per_token=2,
+        num_shared_experts=1, moe_d_ff=384, capacity_factor=None,
+        router_aux="seq", norm_topk_prob=False, experts_held=4,
+        expert_operand_dtype="bfloat16").validate()
+    opt, comp = sgd_momentum(0.9), CompressionConfig(compressor="none")
+    state = jax.eval_shape(lambda k: init_train_state(
+        init_params(cfg, k), opt, workers=2, model_size=2,
+        compression=comp), jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        state, train_state_specs(state, "data"))
+    tok = jax.ShapeDtypeStruct((4, 64), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    with pytest.MonkeyPatch.context() as mp:
+        if not split:
+            mp.setattr(moe, "auto_axes", lambda: {})
+        step = make_train_step(cfg, mesh, opt, constant(0.1),
+                               compression=comp, remat=False)
+        with tuning.use_backend("mosaic"):
+            text = step.lower(state, {"tokens": tok, "labels": tok}) \
+                .compile().as_text()
+    assert text.count("tpu_custom_call") == 9
+    gathered = _gathers_of(text, ("4,256,384", "4,384,256"))
+    assert (gathered == 0) if split else (gathered > 0), gathered
