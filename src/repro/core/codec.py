@@ -23,11 +23,13 @@ The codec contract every producer/consumer relies on:
   error-feedback residual via the conservation identity
   ``u == decode(encode(u)) + residual``.
 
-``compact_by_mask`` finds each of the ``k_cap`` slots by a binary
-search of ``cumsum(mask)`` and gathers its value, where
-``k_cap * ceil(log2(d + 1)) <= d``; denser selections scatter every
-element to its slot.  A TPU runs a scatter's ``d`` updates one at a
-time (DESIGN.md §3), so the search is the form sparse selections take.
+``compact_by_mask`` finds each of the ``k_cap`` slots by walking down
+counts of the mask's rows of 128, where ``k_cap * ceil(log2(d + 1)) <=
+d``; denser selections scatter every element to its slot.  A TPU runs
+a scatter's ``d`` updates one at a time, and a prefix sum over ``d``
+elements or a gather of one element at a time slowly too (DESIGN.md
+§3), so the sparse form reads ``d`` only in dense row reductions and
+gathers whole rows of 128.
 """
 from __future__ import annotations
 
@@ -35,8 +37,8 @@ import jax
 import jax.numpy as jnp
 
 SENTINEL = -1
-# entries of the cumsum per step of the search's coarse first rounds
-_STRIDE = 4096
+# entries of a row of counts: the lanes of a TPU vector register
+_ROW = 128
 
 
 @jax.named_scope("ef.compact")
@@ -53,57 +55,98 @@ def compact_by_mask(u: jax.Array, mask: jax.Array, k_cap: int):
     carry ``indices == SENTINEL`` and ``values == 0``.  Real indices are
     strictly increasing, hence duplicate-free.
 
-    Both forms start from ``pos = cumsum(mask) - 1``, each selected
-    element's slot.  Where the ``k_cap`` slots are few against ``d``
-    (``k_cap * ceil(log2(d + 1)) <= d``, a static rule on the shapes),
-    slot ``j``'s index is the first ``i`` with ``pos[i] >= j``: a
-    lower-bound binary search, each round a ``k_cap``-sized gather, its
-    first rounds on every ``_STRIDE``-th entry of ``pos`` and its last
-    ``log2(_STRIDE) + 1`` inside the one stride that holds the answer;
-    then one gather of the values.
-    Otherwise every element scatters to its slot (the unselected and
-    the surplus to a scratch slot cut off after): ``d`` writes, which a
-    TPU performs one at a time (DESIGN.md §3), so it is kept for dense
-    selections.  Both give the same bits.
+    Where the ``k_cap`` slots are few against ``d`` (``k_cap *
+    ceil(log2(d + 1)) <= d``, a static rule on the shapes), slot ``j``'s
+    element is found by :func:`_compact_by_rows`: counts of the mask's
+    rows of 128, counts of those counts' rows, up to a level of at most
+    128, walked down with one gather of a 128-wide row a level.
+    Otherwise every element scatters to its slot ``cumsum(mask) - 1``
+    (the unselected and the surplus to a scratch slot cut off after):
+    ``d`` writes, which a TPU performs one at a time (DESIGN.md §3), so
+    it is kept for dense selections.  Both give the same bits.
     """
     d = u.shape[0]
+    if k_cap * d.bit_length() <= d:  # bit_length: ceil(log2(d + 1))
+        return _compact_by_rows(u, mask, k_cap)
     mask = mask.astype(jnp.int32)
     # position of each selected element in the compacted output
-    pos = jnp.cumsum(mask) - 1
-    if k_cap * d.bit_length() <= d:  # bit_length: ceil(log2(d + 1))
-        return _compact_by_search(u, pos, k_cap)
-    return _compact_by_scatter(u, mask, pos, k_cap)
+    return _compact_by_scatter(u, mask, jnp.cumsum(mask) - 1, k_cap)
 
 
-def _compact_by_search(u: jax.Array, pos: jax.Array, k_cap: int):
-    d = u.shape[0]
+def row_levels(d: int) -> int:
+    """The levels of counts :func:`_compact_by_rows` builds over ``d``
+    elements: the first counts each row of 128 elements, each next one
+    each row of 128 counts of the one before, the last has at most 128
+    entries (1 up to ``d = 128**2``, 2 up to ``128**3``, ...)."""
+    levels, n = 1, -(-d // _ROW)
+    while n > _ROW:
+        levels, n = levels + 1, -(-n // _ROW)
+    return levels
+
+
+def _rows(a: jax.Array) -> jax.Array:
+    """``a`` zero-padded to whole rows of ``_ROW``, as ``(rows, _ROW)``."""
+    return jnp.pad(a, (0, -a.shape[0] % _ROW)).reshape(-1, _ROW)
+
+
+def _compact_by_rows(u: jax.Array, mask: jax.Array, k_cap: int):
+    """Slot ``j``'s element by a walk down row counts of the mask.
+
+    The mask, as ``(d / 128, 128)`` rows, is reduced to its count of
+    kept elements per row, those counts to theirs per row of 128, and
+    so on (:func:`row_levels`): dense reductions, one read of the mask,
+    nothing ``d``-sized but an int8 copy of it (and, where ``d`` is not
+    a multiple of 128, a padded copy of ``u``).  Every slot compares
+    itself with the inclusive prefix of the top level (at most 128
+    entries) to pick its child; at each level below, one gather fetches
+    the chosen child's 128-wide row of in-row prefixes, and the entries
+    not above what is left of ``j`` pick the next child.  At the bottom
+    the slot's mask row and ``u`` row are gathered: an exact triangular
+    matmul gives the mask row's prefix, which gives the column, and a
+    one-hot select of the ``u`` row's bits the value.
+    """
     slots = jnp.arange(k_cap, dtype=jnp.int32)
-    # the first rounds search every _STRIDE-th entry of pos, the rest
-    # search the one stride of pos that holds the answer
-    block = jnp.searchsorted(pos[_STRIDE - 1::_STRIDE], slots, side="left",
-                             method="scan").astype(jnp.int32)
-    lo = block * _STRIDE
-    at = _lower_bound(pos, slots, lo, jnp.minimum(lo + _STRIDE, d),
-                      min(_STRIDE, d).bit_length())
-    real = slots <= pos[-1]
-    indices = jnp.where(real, at, SENTINEL)
-    values = jnp.where(real, u[jnp.minimum(at, d - 1)],
-                       jnp.zeros((), u.dtype))
+    mask_rows = _rows(mask.astype(jnp.int8))
+    counts = [mask_rows.sum(axis=1, dtype=jnp.int32)]
+    for _ in range(row_levels(u.shape[0]) - 1):
+        counts.append(_rows(counts[-1]).sum(axis=1))
+    # the top level, against every slot: its child, and the kept
+    # elements before that child
+    top = jnp.cumsum(counts.pop())
+    below = top <= slots[:, None]
+    row = below.sum(axis=1, dtype=jnp.int32)
+    base = jnp.max(jnp.where(below, top, 0), axis=1)
+    for level in reversed(counts):
+        prefix = jnp.take(jnp.cumsum(_rows(level), axis=1), row, axis=0,
+                          mode="clip")
+        below = prefix <= (slots - base)[:, None]
+        row = row * _ROW + below.sum(axis=1, dtype=jnp.int32)
+        base = base + jnp.max(jnp.where(below, prefix, 0), axis=1)
+    kept = jnp.take(mask_rows, row, axis=0, mode="clip")
+    # in-row prefix by a matmul of 0/1 operands: exact in bf16, summed
+    # in f32 (at most 128)
+    upper = jnp.triu(jnp.ones((_ROW, _ROW), jnp.bfloat16))
+    prefix = jnp.matmul(kept.astype(jnp.bfloat16), upper,
+                        preferred_element_type=jnp.float32)
+    col = (prefix <= (slots - base)[:, None].astype(jnp.float32)).sum(
+        axis=1, dtype=jnp.int32)
+    values = _pick(jnp.take(_rows(u), row, axis=0, mode="clip"), col)
+    real = slots < top[-1]
+    indices = jnp.where(real, row * _ROW + col, SENTINEL)
+    values = jnp.where(real, values, jnp.zeros((), u.dtype))
     return values, indices
 
 
-def _lower_bound(a: jax.Array, q: jax.Array, lo: jax.Array, hi: jax.Array,
-                 rounds: int) -> jax.Array:
-    """The first ``i`` in ``[lo, hi)`` with ``a[i] >= q`` (``hi`` if
-    none), for a sorted ``a``, elementwise over ``q``; ``rounds`` must
-    be at least ``ceil(log2(hi - lo + 1))``."""
-    def halve(_, bounds):
-        lo, hi = bounds
-        mid = lo + (hi - lo) // 2
-        left = a[jnp.minimum(mid, a.shape[0] - 1)] >= q
-        return jnp.where(left, lo, mid + 1), jnp.where(left, mid, hi)
-
-    return jax.lax.fori_loop(0, rounds, halve, (lo, hi))[1]
+def _pick(rows: jax.Array, col: jax.Array) -> jax.Array:
+    """``rows[i, col[i]]``, bit for bit, by a one-hot select of the
+    elements' bits and a row reduction (``take_along_axis`` would
+    compile to one more gather of one element a slot)."""
+    bits = jnp.dtype(f"int{8 * rows.dtype.itemsize}")
+    hot = jnp.arange(_ROW, dtype=jnp.int32) == col[:, None]
+    # one nonzero term a row: the sum is exact in the elements' width
+    picked = jnp.sum(jnp.where(hot, jax.lax.bitcast_convert_type(rows, bits),
+                               0), axis=1, dtype=bits)
+    return jax.lax.bitcast_convert_type(picked, rows.dtype)
 
 
 def _compact_by_scatter(u: jax.Array, mask: jax.Array, pos: jax.Array,

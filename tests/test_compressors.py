@@ -98,7 +98,8 @@ def _compact_by_scatter_oracle(u, mask, k_cap):
     return values[:k_cap], indices[:k_cap]
 
 
-# (d, k_cap, mask density); the search form runs where
+# (d, k_cap, mask density, or (slice, density) for a mask kept only in
+# that slice); the row form (the older cases are named search) runs where
 # k_cap * ceil(log2(d + 1)) <= d, the scatter form elsewhere
 _COMPACT_CASES = {
     "search_sparse": (4096, 40, 0.005),
@@ -114,7 +115,27 @@ _COMPACT_CASES = {
     "search_d1": (1, 1, 1.0),
     "search_strides": (40_000, 500, 0.01),
     "search_strides_overflow": (40_000, 100, 0.01),
+    # the row form's edges: rows padded, levels of counts added at 128**2
+    # and 128**3, kept elements crowded into one row or its padding, and
+    # overflow by none and by one
+    "rows_d_unaligned": (1000, 40, 0.02),
+    "rows_d_under_row": (100, 5, 0.1),
+    "rows_d_128sq": (128 ** 2, 300, 0.01),
+    "rows_d_128sq_plus1": (128 ** 2 + 1, 300, 0.01),
+    "rows_d_128cube_minus1": (128 ** 3 - 1, 2000, 0.0005),
+    "rows_d_128cube_plus1": (128 ** 3 + 1, 800, 0.0005),
+    "rows_all_in_one_row": (4096, 40, (slice(256, 384), 0.2)),
+    "rows_full_row_among_empty": (4096, 200, (slice(384, 512), 1.0)),
+    "rows_only_last_padded_row": (1000, 40, (slice(896, 1000), 0.3)),
+    "rows_overflow_at_cap": (4096, 100, (slice(5, 3705, 37), 1.0)),
+    "rows_overflow_cap_plus1": (4096, 100, (slice(5, 3742, 37), 1.0)),
 }
+
+
+def test_row_levels_change_at_powers_of_128():
+    assert [codec.row_levels(d) for d in (
+        1, 128, 128 ** 2, 128 ** 2 + 1, 128 ** 3, 128 ** 3 + 1,
+        205_520_896)] == [1, 1, 1, 2, 2, 3, 3]
 
 
 @pytest.mark.parametrize("rows", [None, 3], ids=["row", "vmap3"])
@@ -126,11 +147,17 @@ def test_compact_by_mask_bits_match_scatter(case, dtype, rows):
     indices, in both of its forms: index order, overflow keeping the
     lowest indices, sentinel slots holding +0."""
     d, k_cap, density = _COMPACT_CASES[case]
-    assert (k_cap * d.bit_length() <= d) == case.startswith("search")
+    assert (k_cap * d.bit_length() <= d) != case.startswith("scatter")
     rng = np.random.default_rng(list(_COMPACT_CASES).index(case))
     shape = (rows or 1, d)
     u = jnp.asarray(rng.normal(size=shape), dtype)
-    mask = jnp.asarray(rng.random(shape) < density)
+    if isinstance(density, tuple):
+        where, density = density
+        mask = np.zeros(shape, bool)
+        mask[:, where] = rng.random(mask[:, where].shape) < density
+        mask = jnp.asarray(mask)
+    else:
+        mask = jnp.asarray(rng.random(shape) < density)
     if rows is None:
         u, mask = u[0], mask[0]
         got = codec.compact_by_mask(u, mask, k_cap)
@@ -143,6 +170,40 @@ def test_compact_by_mask_bits_match_scatter(case, dtype, rows):
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.shape == w.shape
         assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
+# levels of row counts -> leaves that get them, in each benchmark cell
+_CELL_LEVELS = {
+    "stablelm_efjnp_1chip": {1: 3, 3: 9},
+    "dsv2lite_moe_1chip": {1: 7, 2: 3, 3: 17},
+}
+
+
+@pytest.mark.parametrize("workload", list(_CELL_LEVELS))
+def test_benchmark_cells_compact_by_rows(workload):
+    """Every leaf of the benchmark's cells, under its job's ratio and
+    ``leaf_plan``, takes the row form of ``compact_by_mask`` (the form
+    is a static rule on the leaf's shapes), with the levels of counts
+    listed in ``_CELL_LEVELS``."""
+    from collections import Counter
+    from functools import partial
+
+    from bench import spec, system
+    from repro.core.compression import CompressionConfig
+    from repro.dist.layout import build_layout
+    from repro.models import init_params
+
+    cell = spec.load(workload)
+    job = cell["job"]
+    params = jax.eval_shape(partial(init_params, system.model_config(
+        cell["model"])), jax.random.PRNGKey(0))
+    layout = build_layout(params, job["model_size"], CompressionConfig(
+        compressor=job["compressor"], ratio=job["ratio"],
+        strategy=job["strategy"], backend=job["backend"]))
+    for seg in layout.segments:
+        assert seg.k_cap * seg.d_row.bit_length() <= seg.d_row, seg
+    assert Counter(codec.row_levels(seg.d_row)
+                   for seg in layout.segments) == _CELL_LEVELS[workload]
 
 
 @settings(max_examples=25, deadline=None)
